@@ -74,6 +74,9 @@ def write_trajectory(root: str = ".") -> dict:
 
 
 def main() -> int:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sections = [
         ("tableI_ternary_matmul", "benchmarks.bench_ternary_matmul"),
         ("tableII_attention_schedule", "benchmarks.bench_attention_schedule"),

@@ -285,15 +285,13 @@ def swiglu(gate_params: dict, up_params: dict, xq: tuple, *,
             x_i8, x_scale, gate_params["wp"], gate_params["scale"],
             up_params["wp"], up_params["scale"], act_dtype=act_dtype,
         )
-    import jax
-
     g = ternary.ternary_matmul_ref(
         x_i8, x_scale, unpack2(gate_params["wp"]), gate_params["scale"],
         out_dtype=act_dtype)
     u = ternary.ternary_matmul_ref(
         x_i8, x_scale, unpack2(up_params["wp"]), up_params["scale"],
         out_dtype=act_dtype)
-    return ternary.quantize_act(jax.nn.silu(g) * u)
+    return ternary.quantize_act(ternary.silu_mul(g, u))
 
 
 def _swiglu_tl(gate_params, up_params, x_i8, x_scale, *, tables, act_dtype):

@@ -80,10 +80,25 @@ def absmax_scale(x: jax.Array, *, axis: int = -1) -> jax.Array:
 
 
 def quantize_act(x: jax.Array, *, axis: int = -1) -> tuple[jax.Array, jax.Array]:
-    """Hard int8 absmax quantization. Returns (x_i8, scale)."""
-    scale = absmax_scale(x, axis=axis)
-    x_i8 = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
-    return x_i8, scale.astype(jnp.float32)
+    """Hard int8 absmax quantization. Returns (x_i8, scale).
+
+    The arithmetic runs in float32 whatever ``x.dtype`` is: the TPU vector
+    unit has no bf16 rounding, so the Pallas kernels that trace this
+    function could not lower a bf16 form, and every path (kernels, XLA
+    forms, oracles) shares this one definition."""
+    xf = x.astype(jnp.float32)
+    scale = absmax_scale(xf, axis=axis)
+    x_i8 = jnp.clip(jnp.round(xf / scale), -127, 127).astype(jnp.int8)
+    return x_i8, scale
+
+
+def silu_mul(g: jax.Array, u: jax.Array) -> jax.Array:
+    """SwiGLU's hidden activation ``silu(g) * u``, computed in float32 and
+    rounded once to ``g.dtype``. The fused SwiGLU kernels, their XLA forms
+    and the unfused MLP all call this, so the int8 codes requantized from it
+    agree bit-for-bit (Mosaic lowers no bf16 SiLU on TPU v5e)."""
+    return (jax.nn.silu(g.astype(jnp.float32))
+            * u.astype(jnp.float32)).astype(g.dtype)
 
 
 def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -102,14 +117,23 @@ def dequantize_kv(x_i8: jax.Array, scale: jax.Array, dtype=jnp.float32) -> jax.A
     ``[..., D]`` in ``dtype`` (the attention compute dtype). The dequant runs
     in f32 and casts once at the end — the semantics every quantized attention
     path (kernel, XLA form, oracle) implements on the VMEM-resident block."""
-    return (x_i8.astype(jnp.float32) * scale[..., None].astype(jnp.float32)).astype(dtype)
+    return dequantize_rows(x_i8, scale[..., None], dtype)
+
+
+def dequantize_rows(x_i8: jax.Array, scale: jax.Array,
+                    dtype=jnp.float32) -> jax.Array:
+    """:func:`dequantize_kv` with the scale axis kept (``scale [..., 1]``),
+    the form a Pallas kernel holds its per-row scales in."""
+    return (x_i8.astype(jnp.float32) * scale.astype(jnp.float32)).astype(dtype)
 
 
 def quantize_act_ste(x: jax.Array, *, axis: int = -1) -> jax.Array:
-    """Fake-quant int8 activations with straight-through gradients (value
-    cast back to ``x.dtype`` — see ternarize_ste / §Perf A2)."""
-    scale = absmax_scale(x, axis=axis)
-    x_q = jnp.clip(jnp.round(x / scale), -127, 127) * scale
+    """Fake-quant int8 activations with straight-through gradients: the
+    :func:`quantize_act` arithmetic in float32, the value cast back to
+    ``x.dtype`` (see ternarize_ste / §Perf A2)."""
+    xf = x.astype(jnp.float32)
+    scale = absmax_scale(xf, axis=axis)
+    x_q = jnp.clip(jnp.round(xf / scale), -127, 127) * scale
     return (x + jax.lax.stop_gradient(x_q.astype(x.dtype) - x)).astype(x.dtype)
 
 

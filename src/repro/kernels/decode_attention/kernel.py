@@ -23,9 +23,10 @@ the prefill kernel: q is pre-grouped to [B·HK, G, D] so the G query heads that
 share a kv head contract against one streamed k/v block.
 
 **Int8 cache path** (DESIGN.md §kv-cache): with ``quantized=True`` the k/v
-operands are int8 with per-row f32 scales riding alongside as [B·HK, M]
-arrays, blocked by the *same* clamped index map — so a skipped block's scales
-move no HBM traffic either. The block is dequantized in VMEM right before the
+operands are int8 with per-row f32 scales riding alongside as [B·HK, 1, M]
+arrays (the unit axis keeps a (1, bkv) scale block legal under the TPU's
+(8, 128) tiling rule), blocked by the *same* clamped index map — so a
+skipped block's scales move no HBM traffic either. The block is dequantized in VMEM right before the
 QK matmul (``ternary.dequantize_kv`` semantics: f32 multiply, one cast to the
 query dtype); full-precision K/V never exists in HBM, which is the point —
 the phase is bound on cache bytes, and int8+scale halves them.
@@ -41,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core import ternary
+from .. import _common as C
 
 _NEG_INF = -1e30
 
@@ -79,8 +81,8 @@ def _kernel(
         if quantized:
             # in-VMEM dequant right before the QK matmul: the int8 block and
             # its per-row scales are all that ever crossed HBM.
-            k = ternary.dequantize_kv(k, ks_ref[0], q_ref.dtype)
-            v = ternary.dequantize_kv(v, vs_ref[0], q_ref.dtype)
+            k = ternary.dequantize_rows(k, C.row_to_col(ks_ref[0]), q_ref.dtype)
+            v = ternary.dequantize_rows(v, C.row_to_col(vs_ref[0]), q_ref.dtype)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [G, bkv]
@@ -114,7 +116,7 @@ def _call(q, k, v, pos, scales, *, bkv, window, softcap, scale, interpret):
     """Shared pallas_call builder for the dense and int8-cache paths.
 
     ``scales`` is ``None`` (dense bf16 cache) or ``(k_scale, v_scale)`` — the
-    [B*HK, M] f32 per-row side arrays of an int8 cache."""
+    [B*HK, 1, M] f32 per-row side arrays of an int8 cache."""
     bhk, g, d = q.shape
     m = k.shape[1]
     b = pos.shape[0]
@@ -143,7 +145,7 @@ def _call(q, k, v, pos, scales, *, bkv, window, softcap, scale, interpret):
 
     def scale_index(bh, j, pos_ref):
         # the scale side arrays ride the same clamped schedule as their blocks
-        return (bh, live_j(bh, j, pos_ref))
+        return (bh, 0, live_j(bh, j, pos_ref))
 
     in_specs = [
         pl.BlockSpec((1, g, d), lambda bh, j, pos_ref: (bh, 0, 0)),
@@ -152,8 +154,8 @@ def _call(q, k, v, pos, scales, *, bkv, window, softcap, scale, interpret):
     ]
     operands = [pos, q, k, v]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bkv), scale_index),
-                     pl.BlockSpec((1, bkv), scale_index)]
+        in_specs += [pl.BlockSpec((1, 1, bkv), scale_index),
+                     pl.BlockSpec((1, 1, bkv), scale_index)]
         operands += list(scales)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -193,7 +195,7 @@ def _call_paged(q, k, v, page_table, pos, scales, *, bkv, window, softcap,
     a page. The kv index map composes the contiguous frontier clamp with a
     table lookup: logical block ``lj`` → page ``pt[slot, lj·bkv ÷ ps]`` →
     pool row — so skipped blocks still repeat a block index and move zero
-    bytes, page lookup included. ``scales`` pools are [P*HK, ps]."""
+    bytes, page lookup included. ``scales`` pools are [P*HK, 1, ps]."""
     bhk, g, d = q.shape
     p_hk, ps, _ = k.shape
     b, nb = page_table.shape
@@ -222,7 +224,7 @@ def _call_paged(q, k, v, page_table, pos, scales, *, bkv, window, softcap,
     def scale_index(bh, j, pt_ref, pos_ref):
         lj = live_j(bh, j, pt_ref, pos_ref)
         page = pt_ref[bh // hk, (lj * bkv) // ps]
-        return (page * hk + bh % hk, lj % (ps // bkv))
+        return (page * hk + bh % hk, 0, lj % (ps // bkv))
 
     in_specs = [
         pl.BlockSpec((1, g, d), lambda bh, j, pt_ref, pos_ref: (bh, 0, 0)),
@@ -231,8 +233,8 @@ def _call_paged(q, k, v, page_table, pos, scales, *, bkv, window, softcap,
     ]
     operands = [page_table, pos, q, k, v]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bkv), scale_index),
-                     pl.BlockSpec((1, bkv), scale_index)]
+        in_specs += [pl.BlockSpec((1, 1, bkv), scale_index),
+                     pl.BlockSpec((1, 1, bkv), scale_index)]
         operands += list(scales)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -282,8 +284,8 @@ def decode_attention_paged_kernel_quant(
     q: jax.Array,           # [B*HK, G, D] grouped queries
     k: jax.Array,           # [P*HK, ps, D] int8 page pool
     v: jax.Array,           # [P*HK, ps, D]
-    k_scale: jax.Array,     # [P*HK, ps] f32 per-row scales
-    v_scale: jax.Array,     # [P*HK, ps]
+    k_scale: jax.Array,     # [P*HK, 1, ps] f32 per-row scales
+    v_scale: jax.Array,     # [P*HK, 1, ps]
     page_table: jax.Array,  # [B, NB] int32
     pos: jax.Array,         # [B] int32 per-slot frontier
     *,
@@ -325,8 +327,8 @@ def decode_attention_kernel_quant(
     q: jax.Array,        # [B*HK, G, D] grouped queries
     k: jax.Array,        # [B*HK, M, D] int8 cache
     v: jax.Array,        # [B*HK, M, D] int8 cache
-    k_scale: jax.Array,  # [B*HK, M] f32 per-row scales
-    v_scale: jax.Array,  # [B*HK, M]
+    k_scale: jax.Array,  # [B*HK, 1, M] f32 per-row scales
+    v_scale: jax.Array,  # [B*HK, 1, M]
     pos: jax.Array,      # [B] int32 per-slot frontier
     *,
     bkv: int = 128,

@@ -68,8 +68,8 @@ def decode_attention(
             qg.reshape(b * hk, gp, d),
             k_cache.reshape(b * hk, mp, d),
             v_cache.reshape(b * hk, mp, d),
-            k_scale.reshape(b * hk, mp).astype(jnp.float32),
-            v_scale.reshape(b * hk, mp).astype(jnp.float32),
+            k_scale.reshape(b * hk, 1, mp).astype(jnp.float32),
+            v_scale.reshape(b * hk, 1, mp).astype(jnp.float32),
             pos,
             bkv=bkv, window=window, softcap=softcap, scale=scale,
             interpret=interpret,
@@ -137,8 +137,8 @@ def decode_attention_paged(
             qg.reshape(b * hk, gp, d),
             k_pool.reshape(p_pages * hk, ps, d),
             v_pool.reshape(p_pages * hk, ps, d),
-            k_scale.reshape(p_pages * hk, ps).astype(jnp.float32),
-            v_scale.reshape(p_pages * hk, ps).astype(jnp.float32),
+            k_scale.reshape(p_pages * hk, 1, ps).astype(jnp.float32),
+            v_scale.reshape(p_pages * hk, 1, ps).astype(jnp.float32),
             page_table, pos,
             bkv=bkv, window=window, softcap=softcap, scale=scale,
             interpret=interpret,

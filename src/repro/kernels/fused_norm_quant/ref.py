@@ -49,8 +49,8 @@ def norm_quant_tables(x, gamma, *, eps: float = 1e-5, tl_g: int = 3):
 def swiglu_requant(g, u):
     """Unfused epilogue oracle: dequantized gate/up outs -> (h_i8, h_scale).
 
-    ``silu(g) * u`` in the activation dtype, then per-token absmax int8 —
-    the exact op sequence the unfused packed MLP runs between the gate/up
-    and down matmuls.
+    ``ternary.silu_mul(g, u)`` (f32, rounded once to the activation dtype),
+    then per-token absmax int8 — the exact op sequence the unfused packed
+    MLP runs between the gate/up and down matmuls.
     """
-    return ternary.quantize_act(jax.nn.silu(g) * u)
+    return ternary.quantize_act(ternary.silu_mul(g, u))

@@ -27,10 +27,12 @@ streamed k/v block; the causal mask depends on the row's intra-chunk index
 ``row % C`` only.
 
 **Int8 cache path** (DESIGN.md §kv-cache): with ``quantized=True`` the cache
-operands are int8 with per-row f32 scale side arrays [B·HK, M], streamed by
-the same clamped index map (skipped prefix blocks move no scale bytes
-either) and appended through their own aliased (1, C) chunk windows. The
-chunk's K/V are absmax-quantized *in VMEM* before anything is stored — the
+operands are int8 with per-row f32 scale side arrays [B·HK, 1, M] (the unit
+axis keeps a (1, bkv) scale block legal under the TPU's (8, 128) tiling
+rule), streamed by the same clamped index map (skipped prefix blocks move no
+scale bytes either) and appended through their own aliased scale windows
+(at least 128 lanes wide, the chunk spliced into the window's old contents).
+The chunk's K/V are absmax-quantized *in VMEM* before anything is stored — the
 QDQ unit fused into the append, so full-precision K/V never reaches HBM —
 and the chunk's self-attention runs on the dequantized quantized rows, so
 the chunk sees exactly the K/V every later reader will.
@@ -46,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core import ternary
+from .. import _common as C
 
 _NEG_INF = -1e30
 
@@ -54,10 +57,11 @@ def _kernel(
     off_ref, q_ref, kn_ref, vn_ref, kc_ref, vc_ref, *rest,
     scale: float, bkv: int, c: int, window: int, softcap: float,
     nkv: int, hk: int, prefix_limit: int, quantized: bool = False,
+    sw: int = 0,
 ):
     if quantized:
-        (ks_ref, vs_ref, o_ref, ko_ref, vo_ref, kso_ref, vso_ref,
-         acc_ref, m_ref, l_ref) = rest
+        (ks_ref, vs_ref, ksw_ref, vsw_ref, o_ref, ko_ref, vo_ref, kso_ref,
+         vso_ref, acc_ref, m_ref, l_ref) = rest
     else:
         o_ref, ko_ref, vo_ref, acc_ref, m_ref, l_ref = rest
     bh = pl.program_id(0)
@@ -110,8 +114,8 @@ def _kernel(
         v = vc_ref[0]
         if quantized:
             # in-VMEM dequant right before the QK matmul (§kv-cache)
-            k = ternary.dequantize_kv(k, ks_ref[0], q_ref.dtype)
-            v = ternary.dequantize_kv(v, vs_ref[0], q_ref.dtype)
+            k = ternary.dequantize_rows(k, C.row_to_col(ks_ref[0]), q_ref.dtype)
+            v = ternary.dequantize_rows(v, C.row_to_col(vs_ref[0]), q_ref.dtype)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
@@ -132,10 +136,11 @@ def _kernel(
             # the fused QDQ unit: quantize the chunk's rows in VMEM, store
             # int8 + scale, and attend to the *dequantized* rows — the chunk
             # sees exactly the K/V every later reader will dequantize.
-            kn_q, ks_n = ternary.quantize_kv(kn)
-            vn_q, vs_n = ternary.quantize_kv(vn)
-            kn = ternary.dequantize_kv(kn_q, ks_n, q_ref.dtype)
-            vn = ternary.dequantize_kv(vn_q, vs_n, q_ref.dtype)
+            # (``quantize_kv`` semantics, with the scales kept as columns.)
+            kn_q, ks_n = ternary.quantize_act(kn)
+            vn_q, vs_n = ternary.quantize_act(vn)
+            kn = ternary.dequantize_rows(kn_q, ks_n, q_ref.dtype)
+            vn = ternary.dequantize_rows(vn_q, vs_n, q_ref.dtype)
         s = jax.lax.dot_general(
             q, kn, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
@@ -149,8 +154,8 @@ def _kernel(
         if quantized:
             ko_ref[0] = kn_q
             vo_ref[0] = vn_q
-            kso_ref[0] = ks_n
-            vso_ref[0] = vs_n
+            kso_ref[0] = C.splice_row(ksw_ref[0], ks_n, off % sw)
+            vso_ref[0] = C.splice_row(vsw_ref[0], vs_n, off % sw)
         else:
             ko_ref[0] = kn_ref[0].astype(ko_ref.dtype)
             vo_ref[0] = vn_ref[0].astype(vo_ref.dtype)
@@ -160,8 +165,11 @@ def _call(q, k_new, v_new, k_cache, v_cache, offset, scales, *,
           bkv, window, softcap, scale, prefix_limit, interpret):
     """Shared pallas_call builder for the dense and int8-cache paths.
 
-    ``scales`` is ``None`` (dense) or ``(k_scale, v_scale)`` — [B*HK, M] f32
-    per-row side arrays, aliased to outputs just like the caches."""
+    ``scales`` is ``None`` (dense) or ``(k_scale, v_scale)`` — [B*HK, 1, M]
+    f32 per-row side arrays, aliased to outputs just like the caches. Their
+    chunk append goes through a (1, 1, sw) window, sw = 128 for chunks
+    narrower than the TPU's 128-lane tile: the window's old scales ride in
+    as an input block and the chunk's are spliced in at lane ``off % sw``."""
     bhk, gc, d = q.shape
     c = k_new.shape[1]
     m = k_cache.shape[1]
@@ -172,10 +180,12 @@ def _call(q, k_new, v_new, k_cache, v_cache, offset, scales, *,
     scale = scale if scale is not None else 1.0 / d**0.5
     nkv = m // bkv
     quantized = scales is not None
+    sw = 128 if c < 128 and m % 128 == 0 else c
 
     kern = functools.partial(
         _kernel, scale=scale, bkv=bkv, c=c, window=window, softcap=softcap,
         nkv=nkv, hk=hk, prefix_limit=prefix_limit, quantized=quantized,
+        sw=sw,
     )
 
     def live_j(bh, j, off_ref):
@@ -192,13 +202,13 @@ def _call(q, k_new, v_new, k_cache, v_cache, offset, scales, *,
         return (bh, live_j(bh, j, off_ref), 0)
 
     def scale_index(bh, j, off_ref):
-        return (bh, live_j(bh, j, off_ref))
+        return (bh, 0, live_j(bh, j, off_ref))
 
     def chunk_out_index(bh, j, off_ref):
         return (bh, off_ref[bh // hk] // c, 0)
 
-    def scale_out_index(bh, j, off_ref):
-        return (bh, off_ref[bh // hk] // c)
+    def scale_window_index(bh, j, off_ref):
+        return (bh, 0, off_ref[bh // hk] // sw)
 
     in_specs = [
         pl.BlockSpec((1, gc, d), lambda bh, j, off_ref: (bh, 0, 0)),
@@ -219,18 +229,20 @@ def _call(q, k_new, v_new, k_cache, v_cache, offset, scales, *,
     ]
     operands = [offset, q, k_new, v_new, k_cache, v_cache]
     # cache operands alias their outputs: the only blocks written back are
-    # the (1, C, D) chunk windows (and, quantized, the (1, C) scale windows)
-    # — the rest of the cache stays resident.
+    # the (1, C, D) chunk windows (and, quantized, the (1, 1, sw) scale
+    # windows) — the rest of the cache stays resident.
     aliases = {4: 1, 5: 2}
     if quantized:
-        in_specs += [pl.BlockSpec((1, bkv), scale_index),
-                     pl.BlockSpec((1, bkv), scale_index)]
-        out_specs += [pl.BlockSpec((1, c), scale_out_index),
-                      pl.BlockSpec((1, c), scale_out_index)]
-        out_shape += [jax.ShapeDtypeStruct((bhk, m), jnp.float32),
-                      jax.ShapeDtypeStruct((bhk, m), jnp.float32)]
-        operands += list(scales)
-        aliases.update({6: 3, 7: 4})
+        in_specs += [pl.BlockSpec((1, 1, bkv), scale_index),
+                     pl.BlockSpec((1, 1, bkv), scale_index),
+                     pl.BlockSpec((1, 1, sw), scale_window_index),
+                     pl.BlockSpec((1, 1, sw), scale_window_index)]
+        out_specs += [pl.BlockSpec((1, 1, sw), scale_window_index),
+                      pl.BlockSpec((1, 1, sw), scale_window_index)]
+        out_shape += [jax.ShapeDtypeStruct((bhk, 1, m), jnp.float32),
+                      jax.ShapeDtypeStruct((bhk, 1, m), jnp.float32)]
+        operands += list(scales) * 2
+        aliases.update({8: 3, 9: 4})
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -317,8 +329,8 @@ def _paged_kernel(
         k = kc_ref[0]  # [bkv, D] — a pool page sub-block
         v = vc_ref[0]
         if quantized:
-            k = ternary.dequantize_kv(k, ks_ref[0], q_ref.dtype)
-            v = ternary.dequantize_kv(v, vs_ref[0], q_ref.dtype)
+            k = ternary.dequantize_rows(k, C.row_to_col(ks_ref[0]), q_ref.dtype)
+            v = ternary.dequantize_rows(v, C.row_to_col(vs_ref[0]), q_ref.dtype)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
@@ -337,10 +349,10 @@ def _paged_kernel(
         kn = kn_ref[0]  # [ps, D] — chunk page t
         vn = vn_ref[0]
         if quantized:
-            kn_q, ks_n = ternary.quantize_kv(kn)
-            vn_q, vs_n = ternary.quantize_kv(vn)
-            kn_d = ternary.dequantize_kv(kn_q, ks_n, q_ref.dtype)
-            vn_d = ternary.dequantize_kv(vn_q, vs_n, q_ref.dtype)
+            kn_q, ks_n = ternary.quantize_act(kn)
+            vn_q, vs_n = ternary.quantize_act(vn)
+            kn_d = ternary.dequantize_rows(kn_q, ks_n, q_ref.dtype)
+            vn_d = ternary.dequantize_rows(vn_q, vs_n, q_ref.dtype)
         else:
             kn_d, vn_d = kn, vn
         s = jax.lax.dot_general(
@@ -354,8 +366,8 @@ def _paged_kernel(
         if quantized:
             ko_ref[0] = kn_q
             vo_ref[0] = vn_q
-            kso_ref[0] = ks_n
-            vso_ref[0] = vs_n
+            kso_ref[0] = C.col_to_row(ks_n)
+            vso_ref[0] = C.col_to_row(vs_n)
         else:
             ko_ref[0] = kn_ref[0].astype(ko_ref.dtype)
             vo_ref[0] = vn_ref[0].astype(vo_ref.dtype)
@@ -370,7 +382,7 @@ def _call_paged(q, k_new, v_new, k_pool, v_pool, page_table, offset, scales,
                 *, bkv, window, softcap, scale, prefix_limit, interpret):
     """Page-indirect pallas_call builder. ``k_pool``/``v_pool`` are page
     pools reshaped to [P*HK, ps, D] (row = page·HK + kv-head); ``scales`` is
-    None or their [P*HK, ps] f32 side pools. The chunk appends through
+    None or their [P*HK, 1, ps] f32 side pools. The chunk appends through
     aliased (1, ps, D) pool windows — each chunk page's last grid visit
     writes the whole window, so the write-back is always complete."""
     bhk, gc, d = q.shape
@@ -405,7 +417,7 @@ def _call_paged(q, k_new, v_new, k_pool, v_pool, page_table, offset, scales,
     def scale_index(bh, j, off_ref, pt_ref):
         lj = live_j(bh, j, off_ref, pt_ref)
         page = pt_ref[bh // hk, (lj * bkv) // ps]
-        return (page * hk + bh % hk, lj % (ps // bkv))
+        return (page * hk + bh % hk, 0, lj % (ps // bkv))
 
     def kn_index(bh, j, off_ref, pt_ref):
         return (bh, jnp.clip(j - nkv, 0, npg - 1), 0)
@@ -419,7 +431,7 @@ def _call_paged(q, k_new, v_new, k_pool, v_pool, page_table, offset, scales,
         return (chunk_out_row(bh, j, off_ref, pt_ref), 0, 0)
 
     def scale_out_index(bh, j, off_ref, pt_ref):
-        return (chunk_out_row(bh, j, off_ref, pt_ref), 0)
+        return (chunk_out_row(bh, j, off_ref, pt_ref), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, gc, d), lambda bh, j, off_ref, pt_ref: (bh, 0, 0)),
@@ -441,12 +453,12 @@ def _call_paged(q, k_new, v_new, k_pool, v_pool, page_table, offset, scales,
     operands = [offset, page_table, q, k_new, v_new, k_pool, v_pool]
     aliases = {5: 1, 6: 2}
     if quantized:
-        in_specs += [pl.BlockSpec((1, bkv), scale_index),
-                     pl.BlockSpec((1, bkv), scale_index)]
-        out_specs += [pl.BlockSpec((1, ps), scale_out_index),
-                      pl.BlockSpec((1, ps), scale_out_index)]
-        out_shape += [jax.ShapeDtypeStruct((p_hk, ps), jnp.float32),
-                      jax.ShapeDtypeStruct((p_hk, ps), jnp.float32)]
+        in_specs += [pl.BlockSpec((1, 1, bkv), scale_index),
+                     pl.BlockSpec((1, 1, bkv), scale_index)]
+        out_specs += [pl.BlockSpec((1, 1, ps), scale_out_index),
+                      pl.BlockSpec((1, 1, ps), scale_out_index)]
+        out_shape += [jax.ShapeDtypeStruct((p_hk, 1, ps), jnp.float32),
+                      jax.ShapeDtypeStruct((p_hk, 1, ps), jnp.float32)]
         operands += list(scales)
         aliases.update({7: 3, 8: 4})
 
@@ -506,8 +518,8 @@ def prefill_append_paged_kernel_quant(
     v_new: jax.Array,       # [B*HK, C, D]
     k_pool: jax.Array,      # [P*HK, ps, D] int8 page pool
     v_pool: jax.Array,      # [P*HK, ps, D]
-    k_scale: jax.Array,     # [P*HK, ps] f32 per-row scales
-    v_scale: jax.Array,     # [P*HK, ps]
+    k_scale: jax.Array,     # [P*HK, 1, ps] f32 per-row scales
+    v_scale: jax.Array,     # [P*HK, 1, ps]
     page_table: jax.Array,  # [B, NB] int32
     offset: jax.Array,      # [B] int32 frontier / write base (≡ 0 mod C)
     *,
@@ -559,8 +571,8 @@ def prefill_append_kernel_quant(
     v_new: jax.Array,    # [B*HK, C, D]
     k_cache: jax.Array,  # [B*HK, M, D] int8 cache
     v_cache: jax.Array,  # [B*HK, M, D] int8 cache
-    k_scale: jax.Array,  # [B*HK, M] f32 per-row scales
-    v_scale: jax.Array,  # [B*HK, M]
+    k_scale: jax.Array,  # [B*HK, 1, M] f32 per-row scales
+    v_scale: jax.Array,  # [B*HK, 1, M]
     offset: jax.Array,   # [B] int32 per-slot frontier / write base (≡ 0 mod C)
     *,
     bkv: int = 128,

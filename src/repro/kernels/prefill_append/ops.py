@@ -64,8 +64,8 @@ def prefill_append(
             v_new.reshape(b * hk, c, d),
             k_cache.reshape(b * hk, m, d),
             v_cache.reshape(b * hk, m, d),
-            k_scale.reshape(b * hk, m).astype(jnp.float32),
-            v_scale.reshape(b * hk, m).astype(jnp.float32),
+            k_scale.reshape(b * hk, 1, m).astype(jnp.float32),
+            v_scale.reshape(b * hk, 1, m).astype(jnp.float32),
             offset,
             bkv=bkv, window=window, softcap=softcap, scale=scale,
             prefix_limit=prefix_limit, interpret=interpret,
@@ -149,8 +149,8 @@ def prefill_append_paged(
             v_new.reshape(b * hk, c, d),
             k_pool.reshape(p_pages * hk, ps, d),
             v_pool.reshape(p_pages * hk, ps, d),
-            k_scale.reshape(p_pages * hk, ps).astype(jnp.float32),
-            v_scale.reshape(p_pages * hk, ps).astype(jnp.float32),
+            k_scale.reshape(p_pages * hk, 1, ps).astype(jnp.float32),
+            v_scale.reshape(p_pages * hk, 1, ps).astype(jnp.float32),
             page_table, offset,
             bkv=bkv, window=window, softcap=softcap, scale=scale,
             prefix_limit=prefix_limit, interpret=interpret,

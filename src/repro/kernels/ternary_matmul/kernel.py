@@ -48,9 +48,10 @@ def _plane_matmul(x_ref, wp_ref):
     n4 = wp_ref.shape[0]
     bm = x_ref.shape[0]
     acc = jnp.zeros((bm, wp_ref.shape[1]), dtype=jnp.int32)
-    wp = wp_ref[...]
+    # Widen before the shift: the TPU vector unit has no 8-bit shifts.
+    wp = wp_ref[...].astype(jnp.int32)
     for j in range(4):
-        plane = (((wp >> (2 * j)) & 0x3).astype(jnp.int32) - 1).astype(jnp.int8)
+        plane = (((wp >> (2 * j)) & 0x3) - 1).astype(jnp.int8)
         xj = x_ref[:, j * n4 : (j + 1) * n4]
         acc = acc + jax.lax.dot_general(
             xj,
@@ -82,7 +83,7 @@ def _swiglu_kernel(x_ref, xs_ref, wg_ref, wgs_ref, wu_ref, wus_ref,
          * wus_ref[0, 0]).astype(act_dtype)
     # dequant → SiLU → (×up) → requant, all on the VMEM-resident block;
     # op-for-op the unfused sequence, so the int8 codes are bit-identical.
-    h_i8, h_s = ternary.quantize_act(jax.nn.silu(g) * u)
+    h_i8, h_s = ternary.quantize_act(ternary.silu_mul(g, u))
     i8_ref[...] = h_i8
     s_ref[...] = h_s
 

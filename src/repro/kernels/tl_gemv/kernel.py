@@ -206,7 +206,7 @@ def _swiglu_kernel(a_ref, xs_ref, wg_ref, wgs_ref, wu_ref, wus_ref, *rest,
     up = (_lookup_acc(tables, wu_ref[...]) * xs * wus_ref[0, 0]).astype(act_dtype)
     # dequant → SiLU → (×up) → requant, op-for-op the packed swiglu kernel's
     # epilogue, so the int8 codes are bit-identical across engines
-    h_i8, h_s = ternary.quantize_act(jax.nn.silu(gate) * up)
+    h_i8, h_s = ternary.quantize_act(ternary.silu_mul(gate, up))
     i8_ref[...] = h_i8
     s_ref[...] = h_s
 

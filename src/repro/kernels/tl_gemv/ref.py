@@ -3,7 +3,6 @@ definition of TL semantics — group packing, zero-trit padding, table build).""
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from ...core import ternary
@@ -44,4 +43,4 @@ def tl_swiglu(x_i8, x_scale, wg_idx, wg_scale, wu_idx, wu_scale, *,
     requant op sequence the packed swiglu paths share."""
     gate = tl_matmul(x_i8, x_scale, wg_idx, wg_scale, g=g, out_dtype=act_dtype)
     up = tl_matmul(x_i8, x_scale, wu_idx, wu_scale, g=g, out_dtype=act_dtype)
-    return ternary.quantize_act(jax.nn.silu(gate) * up)
+    return ternary.quantize_act(ternary.silu_mul(gate, up))

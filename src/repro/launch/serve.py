@@ -41,6 +41,7 @@ from ..configs import get_config
 from ..core import params as P
 from ..models import transformer as Tr
 from ..serving import engine as E
+from .compile_cache import use_compile_cache
 
 
 def _load_requests(path, cfg, args):
@@ -114,6 +115,7 @@ def main(argv=None) -> int:
                          "the human summary")
     ap.add_argument("--ckpt")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = dataclasses.replace(get_config(args.arch, smoke=args.smoke),
                               kv_cache_dtype=args.kv_cache_dtype)

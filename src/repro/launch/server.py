@@ -28,35 +28,39 @@ from ..models import transformer as Tr
 from ..serving import engine as E
 from ..serving.pool import ReplicaPool
 from ..serving.server import ServingServer
+from .compile_cache import use_compile_cache
 
 
-def build_engine(args) -> E.ServingEngine:
-    cfg = dataclasses.replace(get_config(args.arch, smoke=args.smoke),
-                              kv_cache_dtype=args.kv_cache_dtype)
-    specs = Tr.param_specs(cfg)
-    params = Tr.pack_tree(P.init_params(specs, jax.random.PRNGKey(0)), specs)
-    return E.ServingEngine(params, cfg, slots=args.slots,
-                           max_len=args.max_len, mode="packed",
-                           speculative=args.speculative,
-                           queue_cap=args.queue_cap or None)
+def build_config(args):
+    return dataclasses.replace(get_config(args.arch, smoke=args.smoke),
+                               kv_cache_dtype=args.kv_cache_dtype)
 
 
-def build_backend(args):
+def build_backend(args, params=None):
     """One bare engine, or — with ``--replicas N > 1`` — a ReplicaPool of N
-    engines sharing one packed params pytree (byte-identical migration needs
-    identical weights; sharing also keeps host memory flat)."""
+    engines built from one packed params pytree (byte-identical migration
+    needs identical weights). Replica ``i`` lives on ``jax.devices()[i]``
+    (round-robin past the device count): on a four-chip host, four replicas
+    hold one chip each. ``params`` (packed) defaults to a random init from
+    ``--seed``."""
+    cfg = build_config(args)
+    if params is None:
+        specs = Tr.param_specs(cfg)
+        params = Tr.pack_tree(
+            P.init_params(specs, jax.random.PRNGKey(args.seed)), specs)
     if args.replicas <= 1:
-        return build_engine(args)
-    cfg = dataclasses.replace(get_config(args.arch, smoke=args.smoke),
-                              kv_cache_dtype=args.kv_cache_dtype)
-    specs = Tr.param_specs(cfg)
-    params = Tr.pack_tree(P.init_params(specs, jax.random.PRNGKey(0)), specs)
+        return E.ServingEngine(params, cfg, slots=args.slots,
+                               max_len=args.max_len, mode="packed",
+                               speculative=args.speculative,
+                               queue_cap=args.queue_cap or None)
+    devices = jax.devices()
 
     def factory(idx):
         return E.ServingEngine(params, cfg, slots=args.slots,
                                max_len=args.max_len, mode="packed",
                                speculative=args.speculative,
-                               replica_id=idx)
+                               replica_id=idx,
+                               device=devices[idx % len(devices)])
 
     return ReplicaPool(factory, cfg, replicas=args.replicas,
                        queue_cap=args.queue_cap)  # 0 = unbounded, as engine
@@ -79,10 +83,12 @@ async def amain(args) -> int:
     return 0
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--arch", default="tellme-0.7b")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="random weight init seed")
     ap.add_argument("--host", default=None,
                     help="bind host (default: cfg.server_host)")
     ap.add_argument("--port", type=int, default=None,
@@ -102,7 +108,12 @@ def main(argv=None) -> int:
     ap.add_argument("--drain-timeout-s", type=float, default=0.0,
                     help="graceful-drain hard-kill timeout "
                          "(default: cfg.server_drain_timeout_s)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    use_compile_cache()
     return asyncio.run(amain(args))
 
 

@@ -13,7 +13,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..core import bitlinear
+from ..core import bitlinear, ternary
 from ..core.params import ParamSpec
 from ..parallel import constrain
 
@@ -143,7 +143,7 @@ def mlp_spec(dim: int, hidden: int) -> dict:
 def mlp(params: dict, x: jax.Array, *, mode: str = "train") -> jax.Array:
     g = bitlinear.apply(params["gate"], x, mode=mode)
     u = bitlinear.apply(params["up"], x, mode=mode)
-    h = jax.nn.silu(g) * u  # SiLU fused into the gate matmul epilogue on HW
+    h = ternary.silu_mul(g, u)  # SiLU fused into the gate matmul epilogue on HW
     h = constrain(h, "act_batch", None, "act_mlp")
     return bitlinear.apply(params["down"], h, mode=mode)
 

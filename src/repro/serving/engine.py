@@ -53,6 +53,7 @@ tokens across slots — others silently stay on plain decode.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 from functools import partial
@@ -528,7 +529,14 @@ class ServingEngine:
                  fault_plan: R.FaultPlan | None = None, guards: bool = True,
                  clock=time.monotonic,
                  straggler: FT.StragglerMonitor | None = None,
-                 replica_id: int | str | None = None):
+                 replica_id: int | str | None = None,
+                 device: jax.Device | None = None):
+        # ``device`` pins the params, the caches and every tick to one device
+        # (the replica pool puts each replica on its own chip); None leaves
+        # placement to JAX's default device.
+        self.device = device
+        if device is not None:
+            params = jax.device_put(params, device)
         self.params = _engine_params(params, cfg, mode)
         self.cfg, self.mode = cfg, mode
         self.fused = fused  # int8-resident NQD pipeline (None: on iff packed)
@@ -589,15 +597,7 @@ class ServingEngine:
         else:
             self.paged = None
             self._pos_host = None
-        self.caches = init_caches(
-            cfg, slots, self.cache_len, dtype=cfg.dtype,
-            kv_pages=self.paged.num_pages if self.paged is not None else None)
-        self.pos = jnp.zeros((slots,), jnp.int32)
         self.live = [None] * slots  # slot -> Request
-        self.cur_tok = jnp.zeros((slots,), jnp.int32)
-        self.done = jnp.zeros((slots,), bool)
-        self.gen_count = jnp.zeros((slots,), jnp.int32)
-        self.max_new_arr = jnp.zeros((slots,), jnp.int32)
         self.queue: list[Request] = []
         self._plan: list[_PrefillPlan | None] = [None] * slots
         self._pending_first: set[int] = set()  # legacy path: unrecorded prefill token
@@ -616,8 +616,8 @@ class ServingEngine:
                 f"γ+1 verify chunk must fit the chunk_max trash tail")
         # Device-resident token history per slot (prompt + emissions) — the
         # prompt-lookup drafter's corpus; positions <= pos are live.
-        self.hist = (jnp.zeros((slots, self.cache_len), jnp.int32)
-                     if self.speculative else None)
+        self.hist = None
+        self._init_device_state(hist=self.speculative)
         self._spec: dict[int | None, Any] = {}  # chunk (or None) -> spec tick jit
         self.spec_drafted_total = 0
         self.spec_accepted_total = 0
@@ -667,6 +667,23 @@ class ServingEngine:
                                         paged=self.paged is not None)
 
     # -- lifecycle ----------------------------------------------------------
+
+    def _on_device(self):
+        """Pin the arrays created inside the block to this engine's device
+        (a no-op without one)."""
+        if self.device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self.device)
+
+    @property
+    def attn_path(self) -> str:
+        """The attention form the ticks dispatch: ``"auto"`` resolves to the
+        Pallas kernels on a TPU and to the XLA form elsewhere."""
+        if self.attn_impl != "auto":
+            return self.attn_impl
+        platform = (self.device.platform if self.device is not None
+                    else jax.default_backend())
+        return "kernel" if platform == "tpu" else "xla"
 
     def submit(self, req: Request) -> bool:
         """Enqueue ``req``; returns False (terminal ``FAILED``,
@@ -784,16 +801,31 @@ class ServingEngine:
                                  num_pages=self.paged.num_pages,
                                  prefix_cache=self.paged.prefix_cache)
             self._pos_host[:] = 0
-        self.caches = init_caches(
-            self.cfg, self.slots, self.cache_len, dtype=self.cfg.dtype,
-            kv_pages=self.paged.num_pages if self.paged is not None else None)
-        self.pos = jnp.zeros((self.slots,), jnp.int32)
-        self.cur_tok = jnp.zeros((self.slots,), jnp.int32)
-        self.done = jnp.zeros((self.slots,), bool)
-        self.gen_count = jnp.zeros((self.slots,), jnp.int32)
-        self.max_new_arr = jnp.zeros((self.slots,), jnp.int32)
-        if self.hist is not None:
-            self.hist = jnp.zeros((self.slots, self.cache_len), jnp.int32)
+        self._init_device_state(hist=self.hist is not None)
+
+    def _init_device_state(self, *, hist: bool):
+        """Fresh caches and per-slot decode state on the engine's device."""
+        s = self.slots
+        with self._on_device():
+            state = {
+                "caches": init_caches(
+                    self.cfg, s, self.cache_len, dtype=self.cfg.dtype,
+                    kv_pages=(self.paged.num_pages if self.paged is not None
+                              else None)),
+                "pos": jnp.zeros((s,), jnp.int32),
+                "cur_tok": jnp.zeros((s,), jnp.int32),
+                "done": jnp.zeros((s,), bool),
+                "gen_count": jnp.zeros((s,), jnp.int32),
+                "max_new_arr": jnp.zeros((s,), jnp.int32),
+            }
+            if hist:
+                state["hist"] = jnp.zeros((s, self.cache_len), jnp.int32)
+        if self.device is not None:
+            # committed like the tick outputs that replace it, so the first
+            # tick hits the same compiled program as every later one
+            state = jax.device_put(state, self.device)
+        for name, value in state.items():
+            setattr(self, name, value)
 
     def stats(self) -> dict:
         """Engine-level resilience/serving stats for CLIs and tests."""
@@ -809,7 +841,7 @@ class ServingEngine:
             "queued": len(self.queue),
             "live": sum(r is not None for r in self.live),
             "straggler": self.straggler.report(),
-            "attn_impl": self.attn_impl,
+            "attn_impl": self.attn_path,
             "xla_fallback": self.xla_fallback,
             "speculative": self.speculative,
             "spec_acceptance": self.spec_acceptance_rate,
@@ -1461,7 +1493,8 @@ class ServingEngine:
         if self.on_emit is not None or self.on_finish is not None:
             watch = [(r, len(r.generated)) for r in
                      self.queue + [x for x in self.live if x is not None]]
-        out = self._step_impl()
+        with self._on_device():
+            out = self._step_impl()
         if watch is not None:
             for req, n in watch:
                 if self.on_emit is not None and len(req.generated) > n:

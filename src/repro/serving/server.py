@@ -304,17 +304,21 @@ class EngineDriver:
                 pass
 
     def _default_warmup(self) -> None:
-        """Compile the tick jits before /readyz goes true: one short request
+        """Compile the tick jits before /readyz goes true: one request per
+        prefill chunk size the engine can issue, each prompt a single chunk,
         through prefill + decode (rid 0 is never handed out, so the hooks
-        ignore it)."""
+        ignore it). A tick shape left to compile under live traffic stalls
+        its tick for the whole compile — on a TPU long enough for the
+        replica pool to declare the replica hung."""
         eng = self.engine
         vocab = int(getattr(eng.cfg, "vocab_size", 2))
-        req = E.Request(rid=0, prompt=np.arange(1, 9, dtype=np.int64) % vocab,
-                        max_new=2)
-        if eng.submit(req):
-            while not req.done and (eng.queue
-                                    or any(r is not None for r in eng.live)):
-                eng.step()
+        for n in [c for c in eng.chunk_sizes if c < eng.max_len - 2] or [8]:
+            req = E.Request(rid=0, max_new=2,
+                            prompt=np.arange(1, n + 1, dtype=np.int64) % vocab)
+            if eng.submit(req):
+                while not req.done and (
+                        eng.queue or any(r is not None for r in eng.live)):
+                    eng.step()
 
     def _contain(self, detail: str) -> None:
         """An exception escaped ``step()`` (should be impossible post-PR-7):

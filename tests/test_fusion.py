@@ -116,7 +116,7 @@ def _swiglu_inputs(m, n, k, seed=0, act_dtype=jnp.bfloat16):
 def _swiglu_unfused(xi8, xs, gate, up, act_dtype):
     g = T.ternary_matmul_ref(xi8, xs, gate[0], gate[1], out_dtype=act_dtype)
     u = T.ternary_matmul_ref(xi8, xs, up[0], up[1], out_dtype=act_dtype)
-    return T.quantize_act(jax.nn.silu(g) * u)
+    return T.quantize_act(T.silu_mul(g, u))
 
 
 class TestSwigluEpilogue:

@@ -212,3 +212,53 @@ class TestWkvKernel:
         y_seq = jnp.stack(ys, axis=2)
         np.testing.assert_allclose(np.array(y), np.array(y_seq), rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(np.array(sN), np.array(S), rtol=1e-4, atol=1e-4)
+
+
+class TestScaleLayoutHelpers:
+    """The exact lane-row <-> sublane-column moves the int8 attention
+    kernels use on their scale blocks (``kernels/_common.py``)."""
+
+    def test_row_col_roundtrip_is_exact(self):
+        from repro.kernels import _common as C
+
+        row = jax.random.uniform(jax.random.PRNGKey(0), (1, 128)) * 1e-3
+        col = C.row_to_col(row)
+        np.testing.assert_array_equal(np.array(col), np.array(row).T)
+        np.testing.assert_array_equal(np.array(C.col_to_row(col)),
+                                      np.array(row))
+
+    @pytest.mark.parametrize("start", [0, 64])
+    def test_splice_row_keeps_other_lanes(self, start):
+        from repro.kernels import _common as C
+
+        old = jax.random.uniform(jax.random.PRNGKey(1), (1, 128))
+        col = jax.random.uniform(jax.random.PRNGKey(2), (64, 1)) + 2.0
+        got = np.array(C.splice_row(old, col, jnp.int32(start)))
+        want = np.array(old).copy()
+        want[0, start:start + 64] = np.array(col)[:, 0]
+        np.testing.assert_array_equal(got, want)
+
+
+class TestQuantizeActF32:
+    """``quantize_act`` and ``silu_mul`` compute in float32 whatever the
+    input dtype — the one definition every path (kernel, XLA, oracle)
+    shares, and the only one the TPU's vector unit can lower."""
+
+    def test_bf16_input_quantizes_its_f32_value(self):
+        x = (jax.random.normal(jax.random.PRNGKey(3), (4, 96)) * 3
+             ).astype(jnp.bfloat16)
+        i8, s = T.quantize_act(x)
+        i8f, sf = T.quantize_act(x.astype(jnp.float32))
+        assert s.dtype == jnp.float32
+        np.testing.assert_array_equal(np.array(i8), np.array(i8f))
+        np.testing.assert_array_equal(np.array(s), np.array(sf))
+
+    def test_silu_mul_rounds_once(self):
+        g = jax.random.normal(jax.random.PRNGKey(4), (4, 64)).astype(jnp.bfloat16)
+        u = jax.random.normal(jax.random.PRNGKey(5), (4, 64)).astype(jnp.bfloat16)
+        want = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+                ).astype(jnp.bfloat16)
+        got = T.silu_mul(g, u)
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.array(got, np.float32),
+                                      np.array(want, np.float32))

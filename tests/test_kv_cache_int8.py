@@ -244,6 +244,29 @@ class TestPrefillAppendQuant:
             np.testing.assert_array_equal(np.array(ks_c[b, :, :o]),
                                           np.array(kss[b, :, :o]))
 
+    @pytest.mark.parametrize("o", [0, 64, 128, 192])
+    def test_narrow_chunk_splices_scale_window(self, o):
+        # 64-row chunks on a 128-multiple cache append their scales through
+        # a 128-lane window: the chunk lands at lane o % 128 and every other
+        # row — inside the window or not — keeps its old bytes.
+        q, kn, vn, ki, kss, vi, vss = _chunk_inputs(1, 4, 2, 64, 256, 32,
+                                                    key=o + 41)
+        _, k_c, v_c, ks_c, vs_c = pa_ops.prefill_append(
+            q, kn, vn, ki, vi, jnp.array([o], jnp.int32), k_scale=kss,
+            v_scale=vss, interpret=True)
+        kq, ksq = T.quantize_kv(kn)
+        vq, vsq = T.quantize_kv(vn)
+        for got, old, new in ((k_c, ki, kq), (v_c, vi, vq),
+                              (ks_c, kss, ksq), (vs_c, vss, vsq)):
+            got, old = np.array(got), np.array(old)
+            # chunk rows: quantize_kv of the chunk (scales to f32 rounding,
+            # as in test_append_writes_quantized_rows_and_preserves_rest)
+            np.testing.assert_allclose(got[:, :, o:o + 64], np.array(new),
+                                       rtol=1e-6)
+            np.testing.assert_array_equal(got[:, :, :o], old[:, :, :o])
+            np.testing.assert_array_equal(got[:, :, o + 64:],
+                                          old[:, :, o + 64:])
+
     def test_update_kv_cache_quant_scalar_and_vector_pos_agree(self):
         # the two write forms (dynamic_update_slice vs one-hot select) must
         # land identical int8 rows + scales

@@ -396,6 +396,32 @@ class TestPagedKernelOracles:
             np.array(T.gather_kv_pages(ksp, table)), np.array(ksc),
             rtol=1e-6, atol=1e-6)
 
+    def test_prefill_int8_page_sized_chunk_matches_contiguous(self):
+        # the engine's narrowest chunk is one page: one (1, 1, ps) scale
+        # window per grid step, GQA, two slots at different frontiers
+        b, h, hk, d, ps, nb, c = 2, 8, 2, 32, 64, 4, 64
+        k_pool, v_pool, table = _pool_setup(b, hk, ps, nb, d, key=9)
+        kq, ks_ = T.quantize_kv(k_pool)
+        vq, vs_ = T.quantize_kv(v_pool)
+        ks2 = jax.random.split(jax.random.PRNGKey(10), 3)
+        q = jax.random.normal(ks2[0], (b, h, c, d))
+        k_new = jax.random.normal(ks2[1], (b, hk, c, d))
+        v_new = jax.random.normal(ks2[2], (b, hk, c, d))
+        off = jnp.array([c, 3 * c], jnp.int32)
+        got = pa_ops.prefill_append_paged(
+            q, k_new, v_new, kq, vq, table, off,
+            k_scale=ks_, v_scale=vs_, interpret=True)
+        want = pa_ops.prefill_append(
+            q, k_new, v_new, T.gather_kv_pages(kq, table),
+            T.gather_kv_pages(vq, table), off,
+            k_scale=T.gather_kv_pages(ks_, table),
+            v_scale=T.gather_kv_pages(vs_, table), interpret=True)
+        np.testing.assert_allclose(np.array(got[0]), np.array(want[0]),
+                                   rtol=2e-3, atol=2e-3)
+        for pool, dense in zip(got[1:], want[1:]):
+            np.testing.assert_array_equal(
+                np.array(T.gather_kv_pages(pool, table)), np.array(dense))
+
 
 # ---------------------------------------------------------------------------
 # End-to-end engine bit-identity + prefix reuse + scribble

@@ -166,6 +166,24 @@ def test_readyz_false_during_warmup_then_true(setup):
     asyncio.run(body())
 
 
+def test_default_warmup_compiles_every_usable_chunk_size(setup):
+    """/readyz turns true only once each prefill chunk size a prompt below
+    max_len can issue has its tick compiled — live traffic never stalls on
+    a first compile (on a chip, longer than the pool's hang timeout)."""
+    cfg, params = setup
+
+    async def body():
+        server = await _boot(params, cfg)  # max_len 192: chunks 64 and 128
+        try:
+            eng = server.driver.engine
+            assert sorted(eng._fused) == [64, 128]
+            assert eng.stats()["statuses"] == {"OK": 2}
+        finally:
+            await server.drain_and_stop(5.0)
+
+    asyncio.run(body())
+
+
 def test_graceful_drain_finishes_inflight_streams(setup):
     cfg, params = setup
 

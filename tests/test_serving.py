@@ -114,6 +114,32 @@ def test_continuous_batching_tokens_match_reference():
         np.testing.assert_array_equal(np.array(r.generated[:4]), ref[:4])
 
 
+def test_engine_pinned_to_device_serves_identically():
+    """``device=`` commits params, caches and every tick to one device (the
+    replica pool's one-replica-per-chip placement) without changing a
+    token; ``stats()`` names the attention form the ticks dispatch."""
+    cfg = _cfg("tellme-0.7b")
+    params = P.init_params(T.param_specs(cfg), jax.random.PRNGKey(0))
+    dev = jax.devices()[-1]
+    streams = []
+    for kw in ({}, {"device": dev}):
+        eng = E.ServingEngine(params, cfg, slots=2, max_len=96, mode="eval",
+                              **kw)
+        reqs = [E.Request(rid=i, prompt=np.arange(1, 9 + 20 * i), max_new=4)
+                for i in range(3)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run()
+        streams.append([list(r.generated) for r in reqs])
+        assert eng.stats()["attn_impl"] == "xla"  # "auto" off-TPU
+    leaves = jax.tree.leaves((eng.params, eng.caches, eng.pos, eng.cur_tok))
+    assert all(x.devices() == {dev} and x.committed for x in leaves)
+    assert streams[0] == streams[1]
+    eng = E.ServingEngine(params, cfg, slots=2, max_len=96, mode="eval",
+                          attn_impl="kernel")
+    assert eng.stats()["attn_impl"] == "kernel"
+
+
 class TestAdmissionEdgeCases:
     """submit/_admit hardening (DESIGN.md §resilience): degenerate prompts
     must reject with a structured status, never crash the scheduler or

@@ -97,6 +97,34 @@ def test_dryrun_cell_smoke_config():
     assert cost.hbm_bytes > 0
 
 
+def test_compile_cache_env_var_wins(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: JAX owns it, no other path is set."""
+    from repro.launch import compile_cache as CC
+
+    monkeypatch.setenv(CC.ENV_VAR, "/some/where")
+    before = jax.config.jax_compilation_cache_dir
+    assert CC.use_compile_cache() == "/some/where"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_checkout(monkeypatch):
+    """Unset: one fixed, git-ignored directory inside the checkout."""
+    import pathlib
+
+    from repro.launch import compile_cache as CC
+
+    monkeypatch.delenv(CC.ENV_VAR, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = CC.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert path == str(root / ".jax_cache")
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+
+
 def test_skip_reasons():
     import repro.launch.dryrun as dr
 
