@@ -112,6 +112,12 @@ class ModelConfig:
     # Engine event-log ring size (0 = unbounded): stats() bookkeeping on a
     # long-lived server stays fixed-size, with a dropped-events counter.
     stats_ring_events: int = 4096
+    # Span ring of the serving path's tracer (serving/tracing.py, DESIGN.md
+    # §tracing): the newest spans, with a dropped-records counter; 0 turns
+    # recording off. A run of the busiest benchmark cell (set-up plus a 51 s
+    # window, ~7.5 ticks/s at ~8 spans each) records ~3,200 spans: 16384
+    # holds it five times over.
+    trace_ring_spans: int = 16384
     # --- serving: SLO classes + replica pool (DESIGN.md §replica-pool) -----------
     # Admission classes for the multi-replica pool: (name, priority,
     # default_deadline_s, chunk_budget_weight) tuples — tuple-of-tuples so the

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from ..core import ternary
 from ..parallel import constrain
+from .layers import scoped
 
 _NEG = -1e30
 
@@ -59,6 +60,7 @@ def _chunk_attend(q, k, v, q_start, k_start, *, scale, softcap, window, dtype):
     return m, l, o
 
 
+@scoped("attn/core")
 def prefill_attention(
     q: jax.Array,  # [B, H, S, D]
     k: jax.Array,  # [B, HK, S, D]
@@ -137,6 +139,7 @@ def prefill_attention(
     return out
 
 
+@scoped("attn/core")
 def decode_attention(
     q: jax.Array,  # [B, H, D] — the single new token (paper C4 decoupled path)
     k_cache: jax.Array,  # [B, HK, M, D] (bf16/f32, or int8 with scales)
@@ -204,6 +207,7 @@ def decode_attention(
     return o.reshape(b, h, d)
 
 
+@scoped("attn/core")
 def prefill_append_attention(
     q: jax.Array,        # [B, H, C, D] chunk queries (positions offset..offset+C-1)
     k_new: jax.Array,    # [B, HK, C, D] chunk keys
@@ -303,6 +307,7 @@ def prefill_append_attention(
     return o.reshape(b, h, c, d), k_cache, v_cache
 
 
+@scoped("attn/core")
 def decode_attention_paged(
     q: jax.Array,           # [B, H, D]
     k_pool: jax.Array,      # [P, HK, ps, D] page pool (bf16, or int8 + scales)
@@ -347,6 +352,7 @@ def decode_attention_paged(
                             impl="xla")
 
 
+@scoped("attn/core")
 def prefill_append_attention_paged(
     q: jax.Array,           # [B, H, C, D] chunk queries
     k_new: jax.Array,       # [B, HK, C, D]
@@ -413,6 +419,7 @@ def prefill_append_attention_paged(
             ternary.scatter_kv_pages(v_pool, page_table, vv))
 
 
+@scoped("attn/kv_write")
 def append_kv_cache(k_cache, v_cache, k_new, v_new, offset):
     """Write a C-token chunk's K/V at ``[offset, offset+C)``. k_new [B, HK, C, D].
 
@@ -434,6 +441,7 @@ def append_kv_cache(k_cache, v_cache, k_new, v_new, offset):
     return jnp.where(sel, gk, k_cache), jnp.where(sel, gv, v_cache)
 
 
+@scoped("attn/kv_write")
 def append_kv_cache_quant(k_cache, v_cache, k_scale, v_scale, k_new, v_new,
                           offset):
     """Int8-cache twin of :func:`append_kv_cache`: quantize the chunk's rows
@@ -459,6 +467,7 @@ def append_kv_cache_quant(k_cache, v_cache, k_scale, v_scale, k_new, v_new,
             jnp.where(sel_s, gks, k_scale), jnp.where(sel_s, gvs, v_scale))
 
 
+@scoped("attn/kv_write")
 def update_kv_cache_quant(k_cache, v_cache, k_scale, v_scale, k_new, v_new,
                           pos):
     """Int8-cache twin of :func:`update_kv_cache`: the new token's K/V row is
@@ -490,6 +499,7 @@ def update_kv_cache_quant(k_cache, v_cache, k_scale, v_scale, k_new, v_new,
             jnp.where(ohs, vs[:, :, None], v_scale))
 
 
+@scoped("attn/kv_write")
 def update_kv_cache(k_cache, v_cache, k_new, v_new, pos):
     """Write the new token's K/V at ``pos``. k_new [B, HK, D].
 

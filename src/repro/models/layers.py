@@ -8,6 +8,7 @@ the serving path ``mode="packed"`` consumes 2-bit packed weights.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -16,6 +17,20 @@ import jax.numpy as jnp
 from ..core import bitlinear, ternary
 from ..core.params import ParamSpec
 from ..parallel import constrain
+
+
+def scoped(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``, so its
+    operations carry the name in compiled metadata and in the profiler's
+    ``tf_op`` / ``long_name`` stats. A fresh scope per call (one shared
+    ``named_scope`` object is not safe across tracing threads)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return run
+    return deco
 
 # ---------------------------------------------------------------------------
 # RMSNorm (paper C3: fused with absmax quant on the hardware path)

@@ -226,18 +226,22 @@ def _apply_attn(bp, x, cfg, kind, positions, *, mode, cache=None, pos=None,
     window = cfg.sliding_window if kind.local else 0
     src = xq if xq is not None else x
     uk = use_kernel if mode == "packed" else "auto"
-    q = bitlinear.apply(bp["q"], src, mode=mode, out_dtype=x.dtype, use_kernel=uk)
-    q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    k = bitlinear.apply(bp["k"], src, mode=mode, out_dtype=x.dtype, use_kernel=uk)
-    k = k.reshape(b, s, hk, hd).transpose(0, 2, 1, 3)
-    v = bitlinear.apply(bp["v"], src, mode=mode, out_dtype=x.dtype, use_kernel=uk)
-    v = v.reshape(b, s, hk, hd).transpose(0, 2, 1, 3)
-    if rope is None:
-        rope = L.rope_tables(positions, hd, theta=cfg.rope_theta)
-    rope_h = (rope[0][:, None], rope[1][:, None])  # broadcast over heads
-    q = L.apply_rope_tables(q, rope_h)
-    k = L.apply_rope_tables(k, rope_h)
-    q = constrain(q, "act_batch", "act_heads", None, None)
+    with jax.named_scope("attn/qkv"):
+        q = bitlinear.apply(bp["q"], src, mode=mode, out_dtype=x.dtype,
+                            use_kernel=uk)
+        q = q.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        k = bitlinear.apply(bp["k"], src, mode=mode, out_dtype=x.dtype,
+                            use_kernel=uk)
+        k = k.reshape(b, s, hk, hd).transpose(0, 2, 1, 3)
+        v = bitlinear.apply(bp["v"], src, mode=mode, out_dtype=x.dtype,
+                            use_kernel=uk)
+        v = v.reshape(b, s, hk, hd).transpose(0, 2, 1, 3)
+        if rope is None:
+            rope = L.rope_tables(positions, hd, theta=cfg.rope_theta)
+        rope_h = (rope[0][:, None], rope[1][:, None])  # broadcast over heads
+        q = L.apply_rope_tables(q, rope_h)
+        k = L.apply_rope_tables(k, rope_h)
+        q = constrain(q, "act_batch", "act_heads", None, None)
     # int8-resident cache (DESIGN.md §kv-cache): quantize at every append
     # site, dequantize inside the attention read — full-precision K/V never
     # exists in HBM. The cache dict itself carries the layout (scale leaves
@@ -300,16 +304,17 @@ def _apply_attn(bp, x, cfg, kind, positions, *, mode, cache=None, pos=None,
         if page_table is not None:
             ps = cache["k"].shape[2]
             if quant:
-                k_i8, ks_n = ternary.quantize_kv(k[:, :, 0])
-                v_i8, vs_n = ternary.quantize_kv(v[:, :, 0])
-                k_c = ternary.update_kv_pages(cache["k"], page_table, k_i8,
-                                              pos, ps)
-                v_c = ternary.update_kv_pages(cache["v"], page_table, v_i8,
-                                              pos, ps)
-                ks_c = ternary.update_kv_pages(cache["k_scale"], page_table,
-                                               ks_n, pos, ps)
-                vs_c = ternary.update_kv_pages(cache["v_scale"], page_table,
-                                               vs_n, pos, ps)
+                with jax.named_scope("attn/kv_write"):
+                    k_i8, ks_n = ternary.quantize_kv(k[:, :, 0])
+                    v_i8, vs_n = ternary.quantize_kv(v[:, :, 0])
+                    k_c = ternary.update_kv_pages(cache["k"], page_table,
+                                                  k_i8, pos, ps)
+                    v_c = ternary.update_kv_pages(cache["v"], page_table,
+                                                  v_i8, pos, ps)
+                    ks_c = ternary.update_kv_pages(cache["k_scale"],
+                                                   page_table, ks_n, pos, ps)
+                    vs_c = ternary.update_kv_pages(cache["v_scale"],
+                                                   page_table, vs_n, pos, ps)
                 out = attn_ops.decode_attention_paged(
                     q[:, :, 0], k_c, v_c, page_table, pos, k_scale=ks_c,
                     v_scale=vs_c, window=window,
@@ -318,10 +323,11 @@ def _apply_attn(bp, x, cfg, kind, positions, *, mode, cache=None, pos=None,
                 new_cache = {"k": k_c, "k_scale": ks_c, "v": v_c,
                              "v_scale": vs_c}
             else:
-                k_c = ternary.update_kv_pages(cache["k"], page_table,
-                                              k[:, :, 0], pos, ps)
-                v_c = ternary.update_kv_pages(cache["v"], page_table,
-                                              v[:, :, 0], pos, ps)
+                with jax.named_scope("attn/kv_write"):
+                    k_c = ternary.update_kv_pages(cache["k"], page_table,
+                                                  k[:, :, 0], pos, ps)
+                    v_c = ternary.update_kv_pages(cache["v"], page_table,
+                                                  v[:, :, 0], pos, ps)
                 out = attn_ops.decode_attention_paged(
                     q[:, :, 0], k_c, v_c, page_table, pos, window=window,
                     softcap=cfg.attn_logit_softcap, impl=attn_impl,
@@ -431,7 +437,8 @@ def apply_block(kind: LayerKind, bp, x, cfg, pcfg, positions, *, mode, cache=Non
         rows = x.shape[0] * x.shape[1]
         t1 = bitlinear.resolve_engine(bp["attn"]["q"], rows,
                                       use_kernel=engine) == "tl"
-        hq = L.norm_quant(bp["ln1"], x, eps=cfg.norm_eps, tables=t1)
+        with jax.named_scope("attn/qkv"):
+            hq = L.norm_quant(bp["ln1"], x, eps=cfg.norm_eps, tables=t1)
         x, new_cache = _apply_attn(bp["attn"], x, cfg, kind, positions, mode=mode,
                                    cache=cache, pos=pos, attn_impl=attn_impl,
                                    prefix_limit=prefix_limit, aligned=aligned,
@@ -440,9 +447,10 @@ def apply_block(kind: LayerKind, bp, x, cfg, pcfg, positions, *, mode, cache=Non
         x = constrain(x, "act_batch", "act_seq", None)
         t2 = bitlinear.resolve_engine(bp["ffn"]["gate"], rows,
                                       use_kernel=engine) == "tl"
-        h2q = L.norm_quant(bp["ln2"], x, eps=cfg.norm_eps, tables=t2)
-        x = L.mlp_fused(bp["ffn"], h2q, out_dtype=x.dtype, residual=x,
-                        use_kernel=engine)
+        with jax.named_scope("mlp"):
+            h2q = L.norm_quant(bp["ln2"], x, eps=cfg.norm_eps, tables=t2)
+            x = L.mlp_fused(bp["ffn"], h2q, out_dtype=x.dtype, residual=x,
+                            use_kernel=engine)
         x = constrain(x, "act_batch", "act_seq", None)
         return x, new_cache, aux
 
@@ -468,8 +476,9 @@ def apply_block(kind: LayerKind, bp, x, cfg, pcfg, positions, *, mode, cache=Non
         raise ValueError(kind.mixer)
     x = x + y
     x = constrain(x, "act_batch", "act_seq", None)
-    h2 = L.rmsnorm(bp["ln2"], x, eps=cfg.norm_eps)
-    y2, aux = _apply_ffn(bp["ffn"], h2, cfg, kind, pcfg, mode=mode)
+    with jax.named_scope("mlp"):
+        h2 = L.rmsnorm(bp["ln2"], x, eps=cfg.norm_eps)
+        y2, aux = _apply_ffn(bp["ffn"], h2, cfg, kind, pcfg, mode=mode)
     x = x + y2
     x = constrain(x, "act_batch", "act_seq", None)
     return x, new_cache, aux
@@ -544,8 +553,10 @@ def forward(params, batch, cfg, pcfg=None, *, mode="train", collect_cache=False,
     if collect_cache:
         caches["blocks"] = period_caches
 
-    x = L.rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
-    logits = L.lm_head(params["lm_head"], x, softcap=cfg.final_logit_softcap)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
+        logits = L.lm_head(params["lm_head"], x,
+                           softcap=cfg.final_logit_softcap)
     logits = constrain(logits, "act_batch", "act_seq", "act_vocab")
     return logits, aux_total, (caches if collect_cache else None)
 
@@ -600,8 +611,10 @@ def decode_step(params, batch, caches, pos, cfg, *, mode="eval", attn_impl="auto
     x, blk_caches = jax.lax.scan(body, x, (params["blocks"], caches["blocks"]))
     new_caches["blocks"] = blk_caches
 
-    x = L.rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
-    logits = L.lm_head(params["lm_head"], x, softcap=cfg.final_logit_softcap)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
+        logits = L.lm_head(params["lm_head"], x,
+                           softcap=cfg.final_logit_softcap)
     return logits[:, 0], new_caches
 
 
@@ -656,11 +669,13 @@ def prefill_chunk_step(params, batch, caches, offset, cfg, *, mode="eval",
     x, blk_caches = jax.lax.scan(body, x, (params["blocks"], caches["blocks"]))
     new_caches["blocks"] = blk_caches
 
-    if last_row is not None:
-        x = jnp.take_along_axis(
-            x, jnp.asarray(last_row, jnp.int32)[:, None, None], axis=1)
-    x = L.rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
-    logits = L.lm_head(params["lm_head"], x, softcap=cfg.final_logit_softcap)
+    with jax.named_scope("head"):
+        if last_row is not None:
+            x = jnp.take_along_axis(
+                x, jnp.asarray(last_row, jnp.int32)[:, None, None], axis=1)
+        x = L.rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
+        logits = L.lm_head(params["lm_head"], x,
+                           softcap=cfg.final_logit_softcap)
     if last_row is not None:
         return logits[:, 0], new_caches
     return logits, new_caches

@@ -64,10 +64,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import ternary
+from ..models import layers as Lyr
 from ..models import transformer as Tr
 from ..runtime import fault_tolerance as FT
 from . import resilience as R
 from . import speculative as Sp
+from . import tracing
 from .paging import PagedKV, PagePoolExhausted
 
 
@@ -439,6 +441,13 @@ class Request:
     # drafts offered / drafts accepted across this request's verify ticks.
     spec_drafted: int = 0
     spec_accepted: int = 0
+    # trace stamps (perf_counter ns, DESIGN.md §tracing): front-door arrival,
+    # start of the current queue wait, the latest admission, and the read-back
+    # of the first token
+    t_arrival_ns: int | None = None
+    t_queued_ns: int | None = None
+    t_admitted_ns: int | None = None
+    t_first_ns: int | None = None
 
     @property
     def spec_acceptance(self) -> float:
@@ -466,6 +475,9 @@ def snapshot_request(req: Request) -> Request:
     snap.migrations = req.migrations
     snap.slo = req.slo
     snap.budget_weight = req.budget_weight
+    snap.t_arrival_ns = req.t_arrival_ns
+    snap.t_admitted_ns = req.t_admitted_ns
+    snap.t_first_ns = req.t_first_ns
     return snap
 
 
@@ -645,6 +657,13 @@ class ServingEngine:
         self.events: list[dict] = []  # (kind, tick, ...) resilience events
         self.events_cap = int(getattr(cfg, "stats_ring_events", 4096))
         self.events_dropped = 0
+        # serving-path spans (DESIGN.md §tracing) go to one process-wide
+        # ring, sized by the process's owner; a tick's serve.tick attributes
+        # and the read-back time of its one transfer are kept for the tick's
+        # span and request spans
+        self._tick_attrs: dict = {}
+        self._t_read_ns = 0
+        self._admitted = 0
         # Incremental delivery hooks (DESIGN.md §serving-frontdoor): after
         # every step(), on_emit(req, new_tokens) fires once per request that
         # emitted this tick and on_finish(req) once per request that reached
@@ -697,6 +716,11 @@ class ServingEngine:
         req.done = False
         req.status = R.Status.QUEUED
         req.status_detail = None
+        now = time.perf_counter_ns()
+        if req.t_arrival_ns is None:  # no front door: arrival is the submit
+            req.t_arrival_ns = now
+        req.t_queued_ns = (req.t_arrival_ns if req.t_admitted_ns is None
+                           else now)
         if req.submitted_at is None:
             req.submitted_at = self._clock()
         if req.deadline_s is None and self.cfg.request_ttl_s > 0:
@@ -850,6 +874,7 @@ class ServingEngine:
             "quarantined": self.status_counts.get(R.Status.QUARANTINED, 0),
             "kv_layout": "paged" if self.paged is not None else "contiguous",
             "paged": self.paged.stats() if self.paged is not None else None,
+            "compiles": tracing.recorder().compiles_in(self.replica_id),
         }
 
     def export_requests(self) -> list[Request]:
@@ -941,6 +966,12 @@ class ServingEngine:
             self._finish(None, req, R.Status.CACHE_EXHAUSTED)
             return False
         req.status = R.Status.RUNNING
+        now = time.perf_counter_ns()
+        tracing.interval("serve.queue", req.t_queued_ns or now, now,
+                         rid=req.rid, replica=self.replica_id,
+                         requeued=req.t_admitted_ns is not None)
+        req.t_admitted_ns = now
+        self._admitted += 1
         if self.prefill == "legacy":
             self._prefill_slot(slot, req, prompt, remaining)
             return True
@@ -987,6 +1018,7 @@ class ServingEngine:
                     priority=req.priority, emitted=len(req.generated))
         req.preemptions += 1
         req.status = R.Status.QUEUED
+        req.t_queued_ns = time.perf_counter_ns()
         self.live[slot] = None
         self._plan[slot] = None
         self._pending_first.discard(slot)
@@ -1220,54 +1252,66 @@ class ServingEngine:
     def _fused_tick(self, prefilling: list) -> bool:
         self._maybe_raise_tick_fault()
         slots = self.slots
-        (chunk, selected, chunk_tok, chunk_off, finishing, last_row,
-         fin_pos) = self._plan_chunks(prefilling, self._chunk_budget())
-        dec_active = np.array(
-            [self.live[s] is not None and self._plan[s] is None
-             for s in range(slots)])
-        page_table = self._prepare_tick_pages(
-            selected, chunk, chunk_tok, chunk_off, finishing, last_row,
-            fin_pos, dec_active)
-
-        fused = self._get_fused(chunk)
-        (self.caches, self.cur_tok, self.pos, self.done, self.gen_count,
-         packed) = fused(
-            self.params, self.caches, self.cur_tok, self.pos, self.done,
-            self.gen_count, self.max_new_arr, jnp.asarray(dec_active),
-            jnp.asarray(chunk_tok), jnp.asarray(chunk_off),
-            jnp.asarray(finishing), jnp.asarray(last_row),
-            jnp.asarray(fin_pos), page_table, *self._fault_masks("nan"))
-        state = jax.device_get(packed)  # the tick's one transfer
+        with self._span("serve.tick.plan"):
+            (chunk, selected, chunk_tok, chunk_off, finishing, last_row,
+             fin_pos) = self._plan_chunks(prefilling, self._chunk_budget())
+            dec_active = np.array(
+                [self.live[s] is not None and self._plan[s] is None
+                 for s in range(slots)])
+            page_table = self._prepare_tick_pages(
+                selected, chunk, chunk_tok, chunk_off, finishing, last_row,
+                fin_pos, dec_active)
+            self._note_chunks("fused", chunk, prefilling, selected,
+                              dec_active)
+            fused = self._get_fused(chunk)
+            inputs = [jnp.asarray(x) for x in (dec_active, chunk_tok,
+                                               chunk_off, finishing,
+                                               last_row, fin_pos)]
+            faults = self._fault_masks("nan")
+        with self._span("serve.tick.launch"):
+            (self.caches, self.cur_tok, self.pos, self.done, self.gen_count,
+             packed) = fused(
+                self.params, self.caches, self.cur_tok, self.pos, self.done,
+                self.gen_count, self.max_new_arr, *inputs, page_table,
+                *faults)
+        state = self._transfer(packed)
         tok, _, done_, _ = state[:4]
         guard = state[4] if self.guards else np.zeros((slots,), np.int64)
 
-        if self.paged is not None:  # mirror the device frontier advance
-            self._pos_host[dec_active] += 1
-            self._pos_host[finishing] = fin_pos[finishing]
-        for s in range(slots):
-            req = self.live[s]
-            if req is None:
-                continue
-            if guard[s]:  # numerics guard tripped: discard this tick's output
-                self._quarantine(s, req, guard[s])
-                continue
-            if finishing[s]:
-                self._plan[s] = None
-                if self.paged is not None:  # intern the finished prefill
-                    self.paged.insert_prefix(s)
-                req.generated.append(int(tok[s]))
-                if self.speculative:  # keep the drafter history current
-                    self.hist = self.hist.at[s, int(fin_pos[s])].set(int(tok[s]))
-                if done_[s]:
-                    self._finish(s, req, self._terminal_status(req))
-            elif s in selected:  # mid-prefill: advance the plan
-                p = self._plan[s]
-                p.off += chunk
-                p.ci += 1
-            elif dec_active[s]:
-                req.generated.append(int(tok[s]))
-                if done_[s]:
-                    self._finish(s, req, self._terminal_status(req))
+        emitted = 0
+        with self._span("serve.tick.retire"):
+            if self.paged is not None:  # mirror the device frontier advance
+                self._pos_host[dec_active] += 1
+                self._pos_host[finishing] = fin_pos[finishing]
+            for s in range(slots):
+                req = self.live[s]
+                if req is None:
+                    continue
+                if guard[s]:  # numerics guard tripped: discard the output
+                    self._quarantine(s, req, guard[s])
+                    continue
+                if finishing[s]:
+                    self._plan[s] = None
+                    if self.paged is not None:  # intern the finished prefill
+                        self.paged.insert_prefix(s)
+                    req.generated.append(int(tok[s]))
+                    emitted += 1
+                    self._first_token(req)
+                    if self.speculative:  # keep the drafter history current
+                        self.hist = self.hist.at[s, int(fin_pos[s])].set(
+                            int(tok[s]))
+                    if done_[s]:
+                        self._finish(s, req, self._terminal_status(req))
+                elif s in selected:  # mid-prefill: advance the plan
+                    p = self._plan[s]
+                    p.off += chunk
+                    p.ci += 1
+                elif dec_active[s]:
+                    req.generated.append(int(tok[s]))
+                    emitted += 1
+                    if done_[s]:
+                        self._finish(s, req, self._terminal_status(req))
+        self._tick_attrs["emitted"] = emitted
         return True
 
     # -- the speculative verify (+ optional prefill-chunk) tick ---------------
@@ -1292,137 +1336,198 @@ class ServingEngine:
         ``_fused_tick``/``_decode_tick``, still one host transfer."""
         self._maybe_raise_tick_fault()
         slots, gamma = self.slots, self.spec_gamma
-        dec_active = np.array(
-            [self.live[s] is not None and self._plan[s] is None
-             for s in range(slots)])
-        if prefilling:
-            # verify tokens ride the same chunk-token budget as prefill work:
-            # every decoding slot spends γ+1 chunk rows this tick, the rest
-            # (at least one chunk, so prefill always progresses) go to prompts
-            (chunk, selected, chunk_tok, chunk_off, finishing, last_row,
-             fin_pos) = self._plan_chunks(
-                prefilling, self._chunk_budget()
-                - int(dec_active.sum()) * (gamma + 1))
-        else:
-            chunk = None
-            selected = []
-            chunk_tok = np.zeros((slots, 1), np.int64)
-            chunk_off = np.full((slots,), self.trash_base, np.int32)
-            finishing = np.zeros((slots,), bool)
-            last_row = np.zeros((slots,), np.int32)
-            fin_pos = np.zeros((slots,), np.int32)
-        # verify writes γ+1 frontier rows per decoding slot; rejected rows
-        # roll back by the pointer rewind alone — the pages they landed in
-        # are already exclusive, so no page-table edit is ever needed
-        page_table = self._prepare_tick_pages(
-            selected, chunk, chunk_tok, chunk_off, finishing, last_row,
-            fin_pos, dec_active, dec_span=gamma + 1)
-
-        fused = self._get_spec(chunk)
-        (self.caches, self.hist, self.cur_tok, self.pos, self.done,
-         self.gen_count, packed) = fused(
-            self.params, self.caches, self.hist, self.cur_tok, self.pos,
-            self.done, self.gen_count, self.max_new_arr,
-            jnp.asarray(dec_active), jnp.asarray(chunk_tok),
-            jnp.asarray(chunk_off), jnp.asarray(finishing),
-            jnp.asarray(last_row), jnp.asarray(fin_pos), page_table,
-            *self._fault_masks("nan", "drafter_garbage"))
-        state = jax.device_get(packed)  # the tick's one transfer
+        with self._span("serve.tick.plan"):
+            dec_active = np.array(
+                [self.live[s] is not None and self._plan[s] is None
+                 for s in range(slots)])
+            if prefilling:
+                # verify tokens ride the same chunk-token budget as prefill
+                # work: every decoding slot spends γ+1 chunk rows this tick,
+                # the rest (at least one chunk, so prefill always
+                # progresses) go to prompts
+                (chunk, selected, chunk_tok, chunk_off, finishing, last_row,
+                 fin_pos) = self._plan_chunks(
+                    prefilling, self._chunk_budget()
+                    - int(dec_active.sum()) * (gamma + 1))
+            else:
+                chunk = None
+                selected = []
+                chunk_tok = np.zeros((slots, 1), np.int64)
+                chunk_off = np.full((slots,), self.trash_base, np.int32)
+                finishing = np.zeros((slots,), bool)
+                last_row = np.zeros((slots,), np.int32)
+                fin_pos = np.zeros((slots,), np.int32)
+            # verify writes γ+1 frontier rows per decoding slot; rejected
+            # rows roll back by the pointer rewind alone — the pages they
+            # landed in are already exclusive, so no page-table edit is ever
+            # needed
+            page_table = self._prepare_tick_pages(
+                selected, chunk, chunk_tok, chunk_off, finishing, last_row,
+                fin_pos, dec_active, dec_span=gamma + 1)
+            self._note_chunks("spec", chunk or 0, prefilling, selected,
+                              dec_active)
+            fused = self._get_spec(chunk)
+            inputs = [jnp.asarray(x) for x in (dec_active, chunk_tok,
+                                               chunk_off, finishing,
+                                               last_row, fin_pos)]
+            faults = self._fault_masks("nan", "drafter_garbage")
+        with self._span("serve.tick.launch"):
+            (self.caches, self.hist, self.cur_tok, self.pos, self.done,
+             self.gen_count, packed) = fused(
+                self.params, self.caches, self.hist, self.cur_tok, self.pos,
+                self.done, self.gen_count, self.max_new_arr, *inputs,
+                page_table, *faults)
+        state = self._transfer(packed)
         toks, n_out = state[: gamma + 1], state[gamma + 1]
         drafted_, done_ = state[gamma + 2], state[gamma + 3]
         guard = (state[gamma + 4] if self.guards
                  else np.zeros((slots,), np.int64))
 
-        if self.paged is not None:  # mirror the device frontier advance
+        emitted = 0
+        with self._span("serve.tick.retire"):
+            if self.paged is not None:  # mirror the device frontier advance
+                for s in range(slots):
+                    if dec_active[s]:
+                        self._pos_host[s] += int(n_out[s])
+                self._pos_host[finishing] = fin_pos[finishing]
             for s in range(slots):
-                if dec_active[s]:
-                    self._pos_host[s] += int(n_out[s])
-            self._pos_host[finishing] = fin_pos[finishing]
-        for s in range(slots):
-            req = self.live[s]
-            if req is None:
-                continue
-            if guard[s]:  # numerics guard tripped: discard this tick's output
-                self._quarantine(s, req, guard[s])
-                continue
-            if finishing[s]:
-                self._plan[s] = None
-                if self.paged is not None:  # intern the finished prefill
-                    self.paged.insert_prefix(s)
-                req.generated.append(int(toks[0, s]))
-                if done_[s]:
-                    self._finish(s, req, self._terminal_status(req))
-            elif s in selected:  # mid-prefill: advance the plan
-                p = self._plan[s]
-                p.off += chunk
-                p.ci += 1
-            elif dec_active[s]:
-                n, d = int(n_out[s]), int(drafted_[s])
-                req.generated.extend(int(toks[j, s]) for j in range(n))
-                req.spec_drafted += d
-                req.spec_accepted += min(n - 1, d)
-                self.spec_drafted_total += d
-                self.spec_accepted_total += min(n - 1, d)
-                if done_[s]:
-                    self._finish(s, req, self._terminal_status(req))
-        # acceptance-collapse watchdog: once enough drafts have been offered
-        # to judge the workload, a collapsed acceptance rate means verify
-        # ticks are pure overhead (γ+1-row forwards emitting ~1 token) —
-        # stick to plain decode for the rest of this engine's life.
-        if (self.speculative and self.cfg.spec_disable_after > 0
-                and self.spec_drafted_total >= self.cfg.spec_disable_after
-                and self.spec_acceptance_rate < self.cfg.spec_min_acceptance):
-            self.speculative = False
-            self._event("spec_disabled",
-                        acceptance=round(self.spec_acceptance_rate, 4),
-                        drafted=self.spec_drafted_total)
+                req = self.live[s]
+                if req is None:
+                    continue
+                if guard[s]:  # numerics guard tripped: discard the output
+                    self._quarantine(s, req, guard[s])
+                    continue
+                if finishing[s]:
+                    self._plan[s] = None
+                    if self.paged is not None:  # intern the finished prefill
+                        self.paged.insert_prefix(s)
+                    req.generated.append(int(toks[0, s]))
+                    emitted += 1
+                    self._first_token(req)
+                    if done_[s]:
+                        self._finish(s, req, self._terminal_status(req))
+                elif s in selected:  # mid-prefill: advance the plan
+                    p = self._plan[s]
+                    p.off += chunk
+                    p.ci += 1
+                elif dec_active[s]:
+                    n, d = int(n_out[s]), int(drafted_[s])
+                    req.generated.extend(int(toks[j, s]) for j in range(n))
+                    emitted += n
+                    req.spec_drafted += d
+                    req.spec_accepted += min(n - 1, d)
+                    self.spec_drafted_total += d
+                    self.spec_accepted_total += min(n - 1, d)
+                    if done_[s]:
+                        self._finish(s, req, self._terminal_status(req))
+            # acceptance-collapse watchdog: once enough drafts have been
+            # offered to judge the workload, a collapsed acceptance rate
+            # means verify ticks are pure overhead (γ+1-row forwards
+            # emitting ~1 token) — stick to plain decode for the rest of
+            # this engine's life.
+            if (self.speculative and self.cfg.spec_disable_after > 0
+                    and self.spec_drafted_total >= self.cfg.spec_disable_after
+                    and self.spec_acceptance_rate
+                    < self.cfg.spec_min_acceptance):
+                self.speculative = False
+                self._event("spec_disabled",
+                            acceptance=round(self.spec_acceptance_rate, 4),
+                            drafted=self.spec_drafted_total)
+        self._tick_attrs["emitted"] = emitted
         return True
 
     def _decode_tick(self) -> bool:
         self._maybe_raise_tick_fault()
-        page_table = None
-        if self.paged is not None:
-            ps = self.paged.page_size
-            # one frontier row written per live slot; empty slots write the
-            # garbage page through their released (all-garbage) table rows
-            self._cow_prepare(
-                [(s, [int(self._pos_host[s]) // ps])
-                 for s in range(self.slots) if self.live[s] is not None])
-            page_table = jnp.asarray(self.paged.table)
-        active_np = np.array([r is not None for r in self.live])
-        active = jnp.asarray(active_np)
-        first_tok = self.cur_tok  # includes tokens from legacy prefills this tick
-        logits, self.caches = self._serve(
-            self.params, {"tokens": self.cur_tok[:, None]}, self.caches,
-            self.pos, page_table
-        )
-        extra = (self.caches,) if self.guards else ()
-        (self.cur_tok, self.pos, self.done, self.gen_count, packed) = self._advance(
-            logits, first_tok, self.pos, self.done, self.gen_count,
-            self.max_new_arr, active, *extra, *self._fault_masks("nan"),
-        )
-        state = jax.device_get(packed)  # the tick's single host transfer
+        with self._span("serve.tick.plan"):
+            page_table = None
+            if self.paged is not None:
+                ps = self.paged.page_size
+                # one frontier row written per live slot; empty slots write
+                # the garbage page through their released (all-garbage)
+                # table rows
+                self._cow_prepare(
+                    [(s, [int(self._pos_host[s]) // ps])
+                     for s in range(self.slots) if self.live[s] is not None])
+                page_table = jnp.asarray(self.paged.table)
+            active_np = np.array([r is not None for r in self.live])
+            active = jnp.asarray(active_np)
+            faults = self._fault_masks("nan")
+            self._tick_attrs.update(kind="decode", chunk=0,
+                                    decoding=int(active_np.sum()),
+                                    prefilling=0, selected=0, prefill_rows=0)
+        with self._span("serve.tick.launch"):
+            first_tok = self.cur_tok  # includes legacy prefills' tokens
+            logits, self.caches = self._serve(
+                self.params, {"tokens": self.cur_tok[:, None]}, self.caches,
+                self.pos, page_table
+            )
+            extra = (self.caches,) if self.guards else ()
+            (self.cur_tok, self.pos, self.done, self.gen_count,
+             packed) = self._advance(
+                logits, first_tok, self.pos, self.done, self.gen_count,
+                self.max_new_arr, active, *extra, *faults,
+            )
+        state = self._transfer(packed)
         first, nxt, _, done, _, entry_done = state[:6]
         guard = (state[6] if self.guards
                  else np.zeros((self.slots,), np.int64))
-        if self.paged is not None:  # mirror the device frontier advance
-            self._pos_host[active_np] += 1
-        for slot, req in enumerate(self.live):
-            if req is None:
-                continue
-            if guard[slot]:  # numerics guard tripped: discard this tick's output
-                self._quarantine(slot, req, guard[slot])
-                continue
-            if slot in self._pending_first:
-                req.generated.append(int(first[slot]))
-                self._pending_first.discard(slot)
-                if entry_done[slot]:  # retired on its prefill token
-                    self._finish(slot, req, self._terminal_status(req))
+        emitted = 0
+        with self._span("serve.tick.retire"):
+            if self.paged is not None:  # mirror the device frontier advance
+                self._pos_host[active_np] += 1
+            for slot, req in enumerate(self.live):
+                if req is None:
                     continue
-            req.generated.append(int(nxt[slot]))
-            if done[slot]:
-                self._finish(slot, req, self._terminal_status(req))
+                if guard[slot]:  # numerics guard tripped: discard the output
+                    self._quarantine(slot, req, guard[slot])
+                    continue
+                if slot in self._pending_first:
+                    req.generated.append(int(first[slot]))
+                    emitted += 1
+                    self._first_token(req)
+                    self._pending_first.discard(slot)
+                    if entry_done[slot]:  # retired on its prefill token
+                        self._finish(slot, req, self._terminal_status(req))
+                        continue
+                req.generated.append(int(nxt[slot]))
+                emitted += 1
+                if done[slot]:
+                    self._finish(slot, req, self._terminal_status(req))
+        self._tick_attrs["emitted"] = emitted
         return True
+
+    # -- tick tracing (DESIGN.md §tracing) -----------------------------------
+
+    def _span(self, name: str, **attrs) -> tracing.OpenSpan:
+        return tracing.span(name, replica=self.replica_id, **attrs)
+
+    def _transfer(self, packed) -> np.ndarray:
+        """The tick's one host transfer (``serve.tick.wait``); its end is
+        when this tick's tokens were read back."""
+        with self._span("serve.tick.wait") as wait:
+            state = jax.device_get(packed)
+        self._t_read_ns = wait.end_ns
+        return state
+
+    def _note_chunks(self, kind: str, chunk: int, prefilling: list,
+                     selected: list, dec_active: np.ndarray) -> None:
+        """The ``serve.tick`` attributes of a tick that carries prompt
+        chunks."""
+        rows = sum(min(chunk, self._plan[s].true_len - self._plan[s].off)
+                   for s in selected if self.live[s] is not None)
+        self._tick_attrs.update(kind=kind, chunk=chunk,
+                                decoding=int(dec_active.sum()),
+                                prefilling=len(prefilling),
+                                selected=len(selected), prefill_rows=rows)
+
+    def _first_token(self, req: Request) -> None:
+        """A request's first token was read back: its ``serve.prefill``
+        span, from its latest admission."""
+        if req.t_first_ns is not None:  # resumed after preemption
+            return
+        req.t_first_ns = self._t_read_ns
+        tracing.interval("serve.prefill", req.t_admitted_ns or self._t_read_ns,
+                         self._t_read_ns, rid=req.rid, replica=self.replica_id)
 
     def _dispatch(self) -> bool:
         """Route one tick to the right jit family (recomputed fresh so a
@@ -1493,34 +1598,54 @@ class ServingEngine:
         if self.on_emit is not None or self.on_finish is not None:
             watch = [(r, len(r.generated)) for r in
                      self.queue + [x for x in self.live if x is not None]]
-        with self._on_device():
-            out = self._step_impl()
-        if watch is not None:
-            for req, n in watch:
-                if self.on_emit is not None and len(req.generated) > n:
-                    self.on_emit(req, req.generated[n:])
-                if self.on_finish is not None and req.done:
-                    self.on_finish(req)
+        tick = self.tick_count
+        with self._span("serve.tick", tick=tick) as span:
+            with self._on_device():
+                out = self._step_impl()
+            if watch is not None:
+                with self._span("serve.tick.emit"):
+                    for req, n in watch:
+                        if self.on_emit is not None and len(req.generated) > n:
+                            self.on_emit(req, req.generated[n:])
+                        if self.on_finish is not None and req.done:
+                            self.on_finish(req)
+            if out:
+                span.set(**self._tick_attrs)
+            else:  # nothing dispatched: no tick
+                span.discard()
+        # the tick's span is the straggler watchdog's tick time
+        if out and self.straggler.record(tick, span.duration_s):
+            self._event("straggler", tick=tick,
+                        duration_s=round(span.duration_s, 4))
         return out
 
     def _step_impl(self):
         tick = self.tick_count
-        self._expire_and_cancel(self._clock())
-        if self._fault_plan is not None:
-            # cache_growth: the slot's cache cannot hold the request — the
-            # engine's graceful answer is a CACHE_EXHAUSTED retirement with
-            # every already-emitted token kept.
-            for f in self._fault_plan.at(tick, "cache_growth"):
-                for s in (range(self.slots) if f.slot is None else [f.slot]):
-                    if 0 <= s < self.slots and self.live[s] is not None:
-                        self._event("cache_growth_fault",
-                                    rid=self.live[s].rid, slot=s)
-                        self._finish(s, self.live[s], R.Status.CACHE_EXHAUSTED,
-                                     detail="fault_injected")
-        self._admission()
+        with self._span("serve.tick.admit") as admit:
+            self._admitted = 0
+            self._expire_and_cancel(self._clock())
+            if self._fault_plan is not None:
+                # cache_growth: the slot's cache cannot hold the request —
+                # the engine's graceful answer is a CACHE_EXHAUSTED
+                # retirement with every already-emitted token kept.
+                for f in self._fault_plan.at(tick, "cache_growth"):
+                    for s in (range(self.slots) if f.slot is None
+                              else [f.slot]):
+                        if 0 <= s < self.slots and self.live[s] is not None:
+                            self._event("cache_growth_fault",
+                                        rid=self.live[s].rid, slot=s)
+                            self._finish(s, self.live[s],
+                                         R.Status.CACHE_EXHAUSTED,
+                                         detail="fault_injected")
+            self._admission()
+            admit.set(admitted=self._admitted)
         if all(r is None for r in self.live):
             return False
-        t0 = time.perf_counter()
+        # a dispatched tick's serve.tick attributes; the tick form fills
+        # them in, and a tick that fails before it plans keeps these
+        self._tick_attrs = dict(kind="failed", chunk=0, decoding=0,
+                                prefilling=0, selected=0, prefill_rows=0,
+                                emitted=0)
         try:
             if self._fault_plan is not None:
                 for f in self._fault_plan.at(tick, "slow_tick"):
@@ -1536,9 +1661,6 @@ class ServingEngine:
                 self.consecutive_tick_failures += 1
                 out = self._tick_fallback(exc)
         finally:
-            dur = time.perf_counter() - t0
-            if self.straggler.record(tick, dur):
-                self._event("straggler", duration_s=round(dur, 4))
             self.tick_count += 1
         return out
 
@@ -1680,9 +1802,9 @@ def _serve_step_cached(cfg, mode: str, attn_impl: str, fused: bool | None = None
         # caches are donated (matching the fused tick) so decode-only ticks
         # update the KV cache in place instead of copying it every step —
         # the engine reassigns self.caches from the result each tick.
-        fn = jax.jit(make_serve_step(cfg, mode=mode, attn_impl=attn_impl,
-                                     fused=fused),
-                     donate_argnums=(2,))
+        fn = jax.jit(Lyr.scoped("tick/decode")(
+            make_serve_step(cfg, mode=mode, attn_impl=attn_impl,
+                            fused=fused)), donate_argnums=(2,))
         _SERVE_STEP_CACHE[key_t] = fn
     return fn
 
@@ -1697,9 +1819,9 @@ def _advance_cached(cfg, eos_id: int, max_len: int, guards: bool = False,
         # act_kv_seq axis, so the scale guard skips them by construction
         axes_tree = (Tr.cache_specs(cfg, 1, 1, kv_pages=1 if paged else None)[1]
                      if guards else None)
-        fn = jax.jit(partial(_advance, eos_id=eos_id, max_len=max_len,
-                             guards=guards, debug_faults=debug_faults,
-                             axes_tree=axes_tree))
+        fn = jax.jit(partial(Lyr.scoped("tick/decode")(_advance),
+                             eos_id=eos_id, max_len=max_len, guards=guards,
+                             debug_faults=debug_faults, axes_tree=axes_tree))
         _ADVANCE_CACHE[key_t] = fn
     return fn
 
@@ -1789,7 +1911,7 @@ def _fused_tick_step(cfg, chunk: int, *, mode: str, attn_impl: str,
         packed = jnp.stack(rows)
         return caches, new_tok, new_pos, new_done, new_count, packed
 
-    fn = jax.jit(fused, donate_argnums=(1,))
+    fn = jax.jit(Lyr.scoped("tick/fused")(fused), donate_argnums=(1,))
     _FUSED_TICK_CACHE[key_t] = fn
     return fn
 
@@ -1930,6 +2052,6 @@ def _spec_tick_step(cfg, gamma: int, chunk: int | None, *, mode: str,
         packed = jnp.concatenate([emit_rows.T.astype(jnp.int32), *tail])
         return caches, hist, new_tok, new_pos, new_done, new_count, packed
 
-    fn = jax.jit(tick, donate_argnums=(1, 2))
+    fn = jax.jit(Lyr.scoped("tick/spec")(tick), donate_argnums=(1, 2))
     _SPEC_TICK_CACHE[key_t] = fn
     return fn

@@ -70,6 +70,7 @@ import numpy as np
 
 from . import engine as E
 from . import resilience as R
+from . import tracing
 from .server import EngineDriver
 
 
@@ -185,6 +186,7 @@ class ReplicaPool:
         ``replica_hang``); engine-scoped faults stay the factory's choice."""
         self.cfg = cfg
         self.factory = factory
+        tracing.configure(int(getattr(cfg, "trace_ring_spans", 0)))
         self._clock = clock
         self._warmup = warmup
         self._poll_s = poll_s
@@ -319,6 +321,7 @@ class ReplicaPool:
         """Admit one request into the pool queue. Thread-safe and non-
         blocking (dispatch happens on the supervisor). Returns the rid, or
         ``None`` when draining/stopped or the bounded queue is full (429).
+        The request's ``serve.queue`` span starts here.
 
         The SLO class seeds priority / deadline / chunk-budget weight;
         explicit keyword values override the class defaults. ``submitted_at``
@@ -347,6 +350,7 @@ class ReplicaPool:
             req.slo = slo
             req.budget_weight = weight
             req.submitted_at = self._clock()
+            req.t_arrival_ns = time.perf_counter_ns()
             seq = next(self._seqs)
             if not self.queue.push(req, seq=seq):
                 return None
@@ -638,7 +642,8 @@ class ReplicaPool:
                 return  # disowned: stale replica, migrated, or unknown rid
             new = req.generated[st.delivered:]
             if new and st.sink is not None:
-                st.sink.push(("tokens", [int(t) for t in new]))
+                st.sink.push(("tokens", [int(t) for t in new],
+                              req.t_first_ns, ridx))
             st.delivered += len(new)
 
     def _on_finish(self, ridx: int, req: E.Request) -> None:
@@ -668,7 +673,8 @@ class ReplicaPool:
             req.finished_at = self._clock()
         rem = req.generated[st.delivered:]
         if rem and st.sink is not None:
-            st.sink.push(("tokens", [int(t) for t in rem]))
+            st.sink.push(("tokens", [int(t) for t in rem], req.t_first_ns,
+                          st.replica))
         st.delivered += len(rem)
         if st.sink is not None:
             st.sink.push(("final", req.status.name, req.status_detail,

@@ -68,6 +68,7 @@ import numpy as np
 from ..configs.base import resolve_slo
 from . import engine as E
 from . import resilience as R
+from . import tracing
 
 # Terminal-status → SSE event name. QUARANTINED/FAILED are server-side
 # faults (event: error); everything else is a normal stream end (event:
@@ -170,9 +171,12 @@ class EngineDriver:
 
     async def submit(self, prompt, *, max_new: int, priority: int = 0,
                      deadline_s: float | None = None, slo: str | None = None,
-                     budget_weight: float = 1.0):
+                     budget_weight: float = 1.0,
+                     arrival_ns: int | None = None):
         """Submit on the driver thread; returns ``(rid, sink)`` or ``None``
-        when the bounded admission queue rejected it (the HTTP 429 path)."""
+        when the bounded admission queue rejected it (the HTTP 429 path).
+        ``arrival_ns`` (perf_counter ns) is when the front door took the
+        request; its ``serve.queue`` span starts there."""
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
         rid = next(self._rids)
@@ -184,7 +188,8 @@ class EngineDriver:
                             priority=int(priority),
                             deadline_s=(None if deadline_s is None
                                         else float(deadline_s)),
-                            slo=slo, budget_weight=float(budget_weight))
+                            slo=slo, budget_weight=float(budget_weight),
+                            t_arrival_ns=arrival_ns)
             if self.engine.submit(req):
                 self._sinks[rid] = sink
                 self._reqs[rid] = req
@@ -293,15 +298,23 @@ class EngineDriver:
         self._fail_tracked("server_shutdown")
 
     def _drain_cmds(self) -> None:
-        while True:
-            try:
-                cmd = self._cmds.get_nowait()
-            except thread_queue.Empty:
-                return
-            try:
-                cmd()
-            except Exception:  # noqa: BLE001 — a bad command must not kill us
-                pass
+        with tracing.span("serve.drive.cmds",
+                          replica=self.engine.replica_id) as span:
+            n = 0
+            while True:
+                try:
+                    cmd = self._cmds.get_nowait()
+                except thread_queue.Empty:
+                    break
+                try:
+                    cmd()
+                except Exception:  # noqa: BLE001
+                    pass  # a bad command must not kill the driver
+                n += 1
+            if n:
+                span.set(commands=n)
+            else:
+                span.discard()
 
     def _default_warmup(self) -> None:
         """Compile the tick jits before /readyz goes true: one request per
@@ -353,7 +366,8 @@ class EngineDriver:
             self.emit_listener(req, toks)
         sink = self._sinks.get(req.rid)
         if sink is not None:
-            sink.push(("tokens", [int(t) for t in toks]))
+            sink.push(("tokens", [int(t) for t in toks], req.t_first_ns,
+                       self.engine.replica_id))
 
     def _on_finish(self, req: E.Request) -> None:
         if self.finish_listener is not None:
@@ -390,6 +404,7 @@ class ServingServer:
                  warmup=True, poll_s: float | None = None):
         cfg = engine.cfg
         self.cfg = cfg
+        tracing.configure(int(getattr(cfg, "trace_ring_spans", 0)))
         if getattr(engine, "IS_POOL", False):  # serving.pool.ReplicaPool
             self.pool = engine
             self.driver = None
@@ -532,6 +547,7 @@ class ServingServer:
             s = await self.driver.stats()
         s["draining"] = self.draining
         s["ready"] = self.ready
+        s["trace"] = tracing.recorder().stats()  # process-wide
         await _plain(writer, 200, json.dumps(s), ctype="application/json")
 
     async def _handle_generate(self, reader, writer, headers: dict,
@@ -564,6 +580,7 @@ class ServingServer:
         except (KeyError, TypeError, ValueError) as exc:
             return await _plain(writer, 400, f"bad request: {exc}")
 
+        arrival = time.perf_counter_ns()  # the request's serve.queue starts
         if self.pool is not None:
             sink = _StreamSink(asyncio.get_running_loop())
             rid = self.pool.submit(prompt, max_new=max_new, slo=slo,
@@ -574,7 +591,8 @@ class ServingServer:
             sub = await self.driver.submit(prompt, max_new=max_new,
                                            priority=priority,
                                            deadline_s=deadline_s, slo=slo,
-                                           budget_weight=weight)
+                                           budget_weight=weight,
+                                           arrival_ns=arrival)
         if sub is None:  # bounded admission queue: backpressure, not buffering
             return await _plain(writer, 429, "admission queue full",
                                 extra={"retry-after": "1"})
@@ -608,8 +626,10 @@ class ServingServer:
                     continue
                 item = get_task.result()
                 if item[0] == "tokens":
+                    _, toks, t_first, replica = item
                     if not disconnected:
-                        for t in item[1]:
+                        first = idx == 0
+                        for t in toks:
                             writer.write(sse_event(
                                 "token", {"index": idx, "token": t}))
                             idx += 1
@@ -618,8 +638,13 @@ class ServingServer:
                         except ConnectionError:
                             disconnected = True
                             self._cancel(rid)
+                        if first and not disconnected and t_first is not None:
+                            tracing.interval(
+                                "serve.deliver", t_first,
+                                time.perf_counter_ns(), rid=rid,
+                                replica=replica)
                     else:
-                        idx += len(item[1])
+                        idx += len(toks)
                     get_task = asyncio.ensure_future(sink.queue.get())
                     continue
                 _, status, detail, n_tokens = item
