@@ -9,8 +9,9 @@ Default phase (one chip):
 * streams four concurrent ``POST /v1/generate`` requests with ragged
   prompts (64, 128, 300 and 512 tokens), ``max_new`` 32, and drains;
 * fails unless every stream ends ``done``/``OK``, no ``xla_fallback`` or
-  ``tick_failure`` event fired, attention ran on the Pallas kernels, and the
-  compiled decode and prefill ticks hold a ``tpu_custom_call``;
+  ``tick_failure`` event fired, attention ran on the Pallas kernels with the
+  KV cache written in place (``kv_write``), and the compiled decode and
+  prefill ticks hold a ``tpu_custom_call``;
 * checks the served path against an independent one on the same chip:
   teacher-forced logits of the same token streams through the packed
   serving steps (``prefill_chunk_step`` / ``decode_step`` on the Pallas
@@ -135,12 +136,15 @@ def check_engine(eng, errors: list, name: str) -> None:
                           f"{e.get('error') or e.get('detail')}")
     if st["attn_impl"] != "kernel":
         errors.append(f"{name}: attn_impl={st['attn_impl']!r}, not 'kernel'")
+    if st["kv_write"] != "in_place":
+        errors.append(f"{name}: kv_write={st['kv_write']!r}, not 'in_place'")
     bad = {k: v for k, v in st["statuses"].items()
            if k in ("FAILED", "QUARANTINED") and v}
     if bad:
         errors.append(f"{name}: statuses {bad}")
     log(f"{name}: statuses={st['statuses']} attn_impl={st['attn_impl']} "
-        f"xla_fallback={st['xla_fallback']} ticks={st['ticks']}")
+        f"kv_write={st['kv_write']} xla_fallback={st['xla_fallback']} "
+        f"ticks={st['ticks']}")
 
 
 def count_custom_calls(eng) -> tuple[int, int]:

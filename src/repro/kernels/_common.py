@@ -33,6 +33,17 @@ def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def lane_block(n: int, want: int) -> int:
+    """Block length along a lane axis of length ``n`` that ``n`` needs no
+    padding for: the largest multiple of 128 up to ``want`` that divides
+    ``n``, else ``n`` whole — the two lengths the TPU's (8, 128) tiling rule
+    allows on the minor axis."""
+    for b in range(want - want % 128, 0, -128):
+        if n % b == 0:
+            return b
+    return n
+
+
 def flatten_lead(x: jax.Array) -> tuple[jax.Array, tuple, int]:
     """[..., N] -> ([M, N], lead_shape, M): one row per leading-dim element."""
     *lead, n = x.shape

@@ -22,6 +22,12 @@ Slots at heterogeneous positions therefore coexist in one batched call: each
 the prefill kernel: q is pre-grouped to [B·HK, G, D] so the G query heads that
 share a kv head contract against one streamed k/v block.
 
+The contiguous kernel reads the cache transposed, ``(1, D, bkv)`` blocks of
+[.., D, M] — the layout a TPU holds a [.., M, D] cache in when D is under 128
+lanes — and can write each slot's new row first, into the frontier block it
+reads anyway (DESIGN.md §decode). The paged kernel reads ``(1, bkv, D)``
+blocks of its row-major page pool.
+
 **Int8 cache path** (DESIGN.md §kv-cache): with ``quantized=True`` the k/v
 operands are int8 with per-row f32 scales riding alongside as [B·HK, 1, M]
 arrays (the unit axis keeps a (1, bkv) scale block legal under the TPU's
@@ -48,14 +54,23 @@ _NEG_INF = -1e30
 
 
 def _kernel(
-    pos_ref, q_ref, k_ref, v_ref, *rest,
+    pos_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
     scale: float, bkv: int, window: int, softcap: float, nkv: int, hk: int,
-    quantized: bool = False,
+    quantized: bool = False, transposed: bool = False, write: bool = False,
 ):
-    if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
+    del layer_ref  # consumed by the index maps only
+    rest = list(rest)
+
+    def take(n, cond):
+        return [rest.pop(0) for _ in range(n)] if cond else [None] * n
+
+    ks_ref, vs_ref = take(2, quantized)
+    kn_ref, vn_ref = take(2, write)
+    ksn_ref, vsn_ref = take(2, write and quantized)
+    (o_ref,) = take(1, True)
+    ko_ref, vo_ref = take(2, write)
+    kso_ref, vso_ref = take(2, write and quantized)
+    acc_ref, m_ref, l_ref = rest
     bh = pl.program_id(0)
     j = pl.program_id(1)
     p = pos_ref[bh // hk]  # this slot's frontier position
@@ -76,15 +91,41 @@ def _kernel(
     @pl.when(live)
     def _step():
         q = q_ref[0]  # [G, D]
-        k = k_ref[0]  # [bkv, D]
+        k = k_ref[0]  # [D, bkv] transposed, else [bkv, D]
         v = v_ref[0]
+        ks, vs = (ks_ref[0], vs_ref[0]) if quantized else (None, None)
+        if write:
+            # the slot's new row lands at position p, in the frontier block:
+            # spliced in here, so this step attends to it, and stored back
+            # through the aliased frontier block (other blocks: no match)
+            at = jax.lax.broadcasted_iota(jnp.int32, k.shape, 1) == p - j * bkv
+            k = jnp.where(at, kn_ref[0], k)
+            v = jnp.where(at, vn_ref[0], v)
+            if quantized:
+                at = (jax.lax.broadcasted_iota(jnp.int32, ks.shape, 1)
+                      == p - j * bkv)
+                ks = jnp.where(at, ksn_ref[0], ks)
+                vs = jnp.where(at, vsn_ref[0], vs)
+
+            @pl.when(j == jmax)
+            def _store():
+                ko_ref[0] = k
+                vo_ref[0] = v
+                if quantized:
+                    kso_ref[0] = ks
+                    vso_ref[0] = vs
+
         if quantized:
             # in-VMEM dequant right before the QK matmul: the int8 block and
-            # its per-row scales are all that ever crossed HBM.
-            k = ternary.dequantize_rows(k, C.row_to_col(ks_ref[0]), q_ref.dtype)
-            v = ternary.dequantize_rows(v, C.row_to_col(vs_ref[0]), q_ref.dtype)
+            # its per-row scales are all that ever crossed HBM. A transposed
+            # block holds one row per lane, as its [1, bkv] scales do.
+            if not transposed:
+                ks, vs = C.row_to_col(ks), C.row_to_col(vs)
+            k = ternary.dequantize_rows(k, ks, q_ref.dtype)
+            v = ternary.dequantize_rows(v, vs, q_ref.dtype)
+        kd = 0 if transposed else 1  # the D axis of a k/v block
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q, k, (((1,), (kd,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [G, bkv]
         if softcap > 0:
             s = softcap * jnp.tanh(s / softcap)
@@ -102,7 +143,7 @@ def _kernel(
         m_ref[...] = m_new
         l_ref[...] = l_prev * alpha + pexp.sum(axis=1)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            pexp.astype(v.dtype), v, (((1,), (1 - kd,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -112,23 +153,37 @@ def _kernel(
         o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
 
 
-def _call(q, k, v, pos, scales, *, bkv, window, softcap, scale, interpret):
+def _call(q, k, v, pos, layer, scales, new, *, bkv, window, softcap, scale,
+          interpret):
     """Shared pallas_call builder for the dense and int8-cache paths.
 
-    ``scales`` is ``None`` (dense bf16 cache) or ``(k_scale, v_scale)`` — the
-    [B*HK, 1, M] f32 per-row side arrays of an int8 cache."""
+    ``k``/``v`` are [L*B*HK, D, M]: every layer's cache, stacked, and seen
+    transposed — the layout a TPU holds a ``[.., M, D]`` cache in when D is
+    under 128 lanes, so the view is free — of which ``layer`` ([1] int32, a
+    second scalar-prefetch operand) is read: the kv index map offsets each
+    row by ``layer * B*HK``, so no layer is sliced out. A single layer's
+    cache is the case L = 1, ``layer`` 0. ``scales`` is ``None`` (dense bf16
+    cache) or ``(k_scale, v_scale)`` — the [L*B*HK, 1, M] f32 per-row side
+    arrays of an int8 cache.
+
+    ``new`` (or ``None``) writes each slot's new row at its ``pos`` first:
+    ``(k_row, v_row)`` [B*HK, D, 1] (int8 cache: and their [B*HK, 1, 1]
+    scales), spliced into the frontier block, which goes back through an
+    output aliased to the cache — the call then returns
+    ``(out, k, v[, k_scale, v_scale])``."""
     bhk, g, d = q.shape
-    m = k.shape[1]
+    m = k.shape[2]
     b = pos.shape[0]
     hk = bhk // b
     assert m % bkv == 0, (m, bkv)
     scale = scale if scale is not None else 1.0 / d**0.5
     nkv = m // bkv
     quantized = scales is not None
+    write = new is not None
 
     kern = functools.partial(
         _kernel, scale=scale, bkv=bkv, window=window, softcap=softcap,
-        nkv=nkv, hk=hk, quantized=quantized,
+        nkv=nkv, hk=hk, quantized=quantized, transposed=True, write=write,
     )
 
     def live_j(bh, j, pos_ref):
@@ -140,41 +195,60 @@ def _call(q, k, v, pos, scales, *, bkv, window, softcap, scale, interpret):
         lo = jnp.maximum(p - window + 1, 0) // bkv if window > 0 else 0
         return jnp.clip(j, lo, p // bkv)
 
-    def kv_index(bh, j, pos_ref):
-        return (bh, live_j(bh, j, pos_ref), 0)
+    def kv_index(bh, j, pos_ref, layer_ref):
+        # k/v blocks and their scales: the same clamped schedule
+        return (layer_ref[0] * bhk + bh, 0, live_j(bh, j, pos_ref))
 
-    def scale_index(bh, j, pos_ref):
-        # the scale side arrays ride the same clamped schedule as their blocks
-        return (bh, 0, live_j(bh, j, pos_ref))
+    def frontier_index(bh, j, pos_ref, layer_ref):
+        return (layer_ref[0] * bhk + bh, 0, pos_ref[bh // hk] // bkv)
+
+    def slot_index(bh, j, pos_ref, layer_ref):
+        return (bh, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, g, d), lambda bh, j, pos_ref: (bh, 0, 0)),
-        pl.BlockSpec((1, bkv, d), kv_index),
-        pl.BlockSpec((1, bkv, d), kv_index),
+        pl.BlockSpec((1, g, d), slot_index),
+        pl.BlockSpec((1, d, bkv), kv_index),
+        pl.BlockSpec((1, d, bkv), kv_index),
     ]
-    operands = [pos, q, k, v]
+    operands = [pos, layer, q, k, v]
+    out_specs = [pl.BlockSpec((1, g, d), slot_index)]
+    out_shape = [jax.ShapeDtypeStruct((bhk, g, d), q.dtype)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, bkv), scale_index),
-                     pl.BlockSpec((1, 1, bkv), scale_index)]
+        in_specs += [pl.BlockSpec((1, 1, bkv), kv_index)] * 2
         operands += list(scales)
+    aliases = {}
+    if write:
+        in_specs += [pl.BlockSpec((1, d, 1), slot_index)] * 2
+        out_specs += [pl.BlockSpec((1, d, bkv), frontier_index)] * 2
+        out_shape += [jax.ShapeDtypeStruct(k.shape, k.dtype)] * 2
+        aliases = {3: 1, 4: 2}
+        if quantized:
+            in_specs += [pl.BlockSpec((1, 1, 1), slot_index)] * 2
+            out_specs += [pl.BlockSpec((1, 1, bkv), frontier_index)] * 2
+            out_shape += [jax.ShapeDtypeStruct(scales[0].shape,
+                                               jnp.float32)] * 2
+            aliases.update({5: 3, 6: 4})
+        operands += list(new)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(bhk, nkv),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, g, d), lambda bh, j, pos_ref: (bh, 0, 0)),
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((g, d), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bhk, g, d), q.dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
     )(*operands)
+    return tuple(outs) if write else outs[0]
 
 
 def _paged_kernel(pt_ref, pos_ref, *args, **kw):
@@ -183,7 +257,7 @@ def _paged_kernel(pt_ref, pos_ref, *args, **kw):
     # the contiguous kernel unchanged (logical kv positions are j*bkv+iota
     # whatever pool row the block was fetched from).
     del pt_ref
-    return _kernel(pos_ref, *args, **kw)
+    return _kernel(pos_ref, None, *args, **kw)
 
 
 def _call_paged(q, k, v, page_table, pos, scales, *, bkv, window, softcap,
@@ -305,19 +379,22 @@ def decode_attention_paged_kernel_quant(
     jax.jit, static_argnames=("bkv", "window", "softcap", "scale", "interpret")
 )
 def decode_attention_kernel(
-    q: jax.Array,    # [B*HK, G, D] grouped queries (G padded to sublane)
-    k: jax.Array,    # [B*HK, M, D] cache (M padded to a bkv multiple)
-    v: jax.Array,    # [B*HK, M, D]
-    pos: jax.Array,  # [B] int32 per-slot frontier
+    q: jax.Array,      # [B*HK, G, D] grouped queries (G padded to sublane)
+    k: jax.Array,      # [L*B*HK, D, M] stacked cache, transposed (M a bkv multiple)
+    v: jax.Array,      # [L*B*HK, D, M]
+    pos: jax.Array,    # [B] int32 per-slot frontier
+    layer: jax.Array,  # [1] int32 layer of the stack to read
+    new=None,          # (k_row, v_row) [B*HK, D, 1] to write at pos first
     *,
     bkv: int = 128,
     window: int = 0,
     softcap: float = 0.0,
     scale: float | None = None,
     interpret: bool = False,
-) -> jax.Array:
-    return _call(q, k, v, pos, None, bkv=bkv, window=window, softcap=softcap,
-                 scale=scale, interpret=interpret)
+):
+    """Returns out [B*HK, G, D], or with ``new`` (out, k, v)."""
+    return _call(q, k, v, pos, layer, None, new, bkv=bkv, window=window,
+                 softcap=softcap, scale=scale, interpret=interpret)
 
 
 @functools.partial(
@@ -325,19 +402,24 @@ def decode_attention_kernel(
 )
 def decode_attention_kernel_quant(
     q: jax.Array,        # [B*HK, G, D] grouped queries
-    k: jax.Array,        # [B*HK, M, D] int8 cache
-    v: jax.Array,        # [B*HK, M, D] int8 cache
-    k_scale: jax.Array,  # [B*HK, 1, M] f32 per-row scales
-    v_scale: jax.Array,  # [B*HK, 1, M]
+    k: jax.Array,        # [L*B*HK, D, M] stacked int8 cache, transposed
+    v: jax.Array,        # [L*B*HK, D, M]
+    k_scale: jax.Array,  # [L*B*HK, 1, M] f32 per-row scales
+    v_scale: jax.Array,  # [L*B*HK, 1, M]
     pos: jax.Array,      # [B] int32 per-slot frontier
+    layer: jax.Array,    # [1] int32 layer of the stack to read
+    new=None,            # (k_row, v_row, k_row_scale, v_row_scale)
     *,
     bkv: int = 128,
     window: int = 0,
     softcap: float = 0.0,
     scale: float | None = None,
     interpret: bool = False,
-) -> jax.Array:
+):
     """Int8-cache twin of :func:`decode_attention_kernel`: blocks are
-    dequantized in VMEM right before the QK matmul."""
-    return _call(q, k, v, pos, (k_scale, v_scale), bkv=bkv, window=window,
-                 softcap=softcap, scale=scale, interpret=interpret)
+    dequantized in VMEM right before the QK matmul. With ``new`` (int8
+    rows [B*HK, D, 1], f32 scales [B*HK, 1, 1]) returns
+    (out, k, v, k_scale, v_scale)."""
+    return _call(q, k, v, pos, layer, (k_scale, v_scale), new, bkv=bkv,
+                 window=window, softcap=softcap, scale=scale,
+                 interpret=interpret)

@@ -3,9 +3,11 @@
 The prefill twin of ``kernels/decode_attention`` (DESIGN.md §prefill). One
 grid step owns (slot·kv-head, kv-block); a q-chunk of ``C`` tokens at
 absolute positions ``offset .. offset+C-1`` attends to the slot's existing
-KV-cache *prefix* (positions ``< offset``) plus itself, and the chunk's K/V
-are written straight into the batched cache at the slot's offset — per-request
-caches are never materialized or host-scattered.
+KV-cache *prefix* (positions ``< offset``) plus itself. The wrapper writes the
+chunk's K/V into the batched cache at the slot's offset first, in place
+(``ops.prefill_append``) — per-request caches are never materialized or
+host-scattered — and the contiguous kernel only reads the cache, transposed
+(``[.., D, M]``: the layout a TPU holds it in when D is under 128 lanes).
 
 Schedule, mirroring the paper's reversed-reorder saving (§III-B) mapped onto
 a cache prefix:
@@ -17,9 +19,9 @@ a cache prefix:
     the live range so they also move no HBM traffic;
   * the chunk's own K/V ride in VMEM as separate operands (C ≤ 256): the last
     grid step attends causally within the chunk — the lower-triangular half
-    only, same work shape as the flash kernel's diagonal block — and stores
-    the chunk into the cache through aliased output blocks of shape (1, C, D)
-    at block index ``offset // C`` (the engine keeps ``offset ≡ 0 (mod C)``).
+    only, same work shape as the flash kernel's diagonal block. The paged
+    kernel stores the chunk through aliased page windows instead (the engine
+    keeps ``offset ≡ 0 (mod C)``).
 
 GQA uses the same index-map trick as the decode kernel: q is pre-grouped to
 [B·HK, G·C, D] so the G query heads sharing a kv head contract against one
@@ -30,12 +32,11 @@ streamed k/v block; the causal mask depends on the row's intra-chunk index
 operands are int8 with per-row f32 scale side arrays [B·HK, 1, M] (the unit
 axis keeps a (1, bkv) scale block legal under the TPU's (8, 128) tiling
 rule), streamed by the same clamped index map (skipped prefix blocks move no
-scale bytes either) and appended through their own aliased scale windows
-(at least 128 lanes wide, the chunk spliced into the window's old contents).
-The chunk's K/V are absmax-quantized *in VMEM* before anything is stored — the
-QDQ unit fused into the append, so full-precision K/V never reaches HBM —
-and the chunk's self-attention runs on the dequantized quantized rows, so
-the chunk sees exactly the K/V every later reader will.
+scale bytes either). The contiguous kernel takes the chunk's rows quantized,
+with their scales, as the cache holds them; the paged kernel quantizes them
+*in VMEM* and appends them through its aliased page windows. Either way the
+chunk's self-attention runs on the dequantized quantized rows, so the chunk
+sees exactly the K/V every later reader will.
 """
 
 from __future__ import annotations
@@ -54,16 +55,16 @@ _NEG_INF = -1e30
 
 
 def _kernel(
-    off_ref, q_ref, kn_ref, vn_ref, kc_ref, vc_ref, *rest,
+    off_ref, layer_ref, q_ref, kn_ref, vn_ref, kc_ref, vc_ref, *rest,
     scale: float, bkv: int, c: int, window: int, softcap: float,
     nkv: int, hk: int, prefix_limit: int, quantized: bool = False,
-    sw: int = 0,
 ):
+    del layer_ref  # consumed by the index maps only
     if quantized:
-        (ks_ref, vs_ref, ksw_ref, vsw_ref, o_ref, ko_ref, vo_ref, kso_ref,
-         vso_ref, acc_ref, m_ref, l_ref) = rest
+        (ks_ref, vs_ref, o_ref, kq_ref, vq_ref, ksq_ref, vsq_ref,
+         acc_ref, m_ref, l_ref) = rest
     else:
-        o_ref, ko_ref, vo_ref, acc_ref, m_ref, l_ref = rest
+        o_ref, acc_ref, m_ref, l_ref = rest
     bh = pl.program_id(0)
     j = pl.program_id(1)
     off = off_ref[bh // hk]  # this slot's cache frontier (chunk write base)
@@ -79,7 +80,7 @@ def _kernel(
     def _row_i(cols):
         return jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) % c
 
-    def _online_update(s, kpos, v):
+    def _online_update(s, kpos, v, v_key_axis):
         qpos = off + _row_i(s.shape[1])
         mask = kpos <= qpos
         if window > 0:
@@ -93,7 +94,7 @@ def _kernel(
         m_ref[...] = m_new
         l_ref[...] = l_prev * alpha + p.sum(axis=1)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (v_key_axis,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -110,82 +111,80 @@ def _kernel(
     @pl.when(live)
     def _prefix():
         q = q_ref[0]  # [G*C, D]
-        k = kc_ref[0]  # [bkv, D]
+        k = kc_ref[0]  # [D, bkv]: the cache is read transposed
         v = vc_ref[0]
         if quantized:
-            # in-VMEM dequant right before the QK matmul (§kv-cache)
-            k = ternary.dequantize_rows(k, C.row_to_col(ks_ref[0]), q_ref.dtype)
-            v = ternary.dequantize_rows(v, C.row_to_col(vs_ref[0]), q_ref.dtype)
+            # in-VMEM dequant right before the QK matmul (§kv-cache); one
+            # cache row per lane, as its [1, bkv] scales
+            k = ternary.dequantize_rows(k, ks_ref[0], q_ref.dtype)
+            v = ternary.dequantize_rows(v, vs_ref[0], q_ref.dtype)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         if softcap > 0:
             s = softcap * jnp.tanh(s / softcap)
         kpos = j * bkv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # prefix keys only: positions >= off belong to the chunk phase
         kpos = jnp.where(kpos < off, kpos, jnp.int32(2**30))
-        _online_update(s, kpos, v)
+        _online_update(s, kpos, v, 1)
 
-    # --- chunk phase: causal self-attention + the cache append --------------
+    # --- chunk phase: causal self-attention ---------------------------------
     @pl.when(j == nkv)
     def _chunk():
         q = q_ref[0]
         kn = kn_ref[0]  # [C, D]
         vn = vn_ref[0]
         if quantized:
-            # the fused QDQ unit: quantize the chunk's rows in VMEM, store
-            # int8 + scale, and attend to the *dequantized* rows — the chunk
-            # sees exactly the K/V every later reader will dequantize.
-            # (``quantize_kv`` semantics, with the scales kept as columns.)
-            kn_q, ks_n = ternary.quantize_act(kn)
-            vn_q, vs_n = ternary.quantize_act(vn)
-            kn = ternary.dequantize_rows(kn_q, ks_n, q_ref.dtype)
-            vn = ternary.dequantize_rows(vn_q, vs_n, q_ref.dtype)
+            # the fused QDQ unit: quantize the chunk's rows in VMEM, hand
+            # them back for the cache, and attend to the *dequantized* rows —
+            # the chunk sees exactly the K/V every later reader will
+            # (``quantize_kv`` semantics, with the scales kept as columns)
+            kq, ks_n = ternary.quantize_act(kn)
+            vq, vs_n = ternary.quantize_act(vn)
+            kq_ref[0], vq_ref[0], ksq_ref[0], vsq_ref[0] = kq, vq, ks_n, vs_n
+            kn = ternary.dequantize_rows(kq, ks_n, q_ref.dtype)
+            vn = ternary.dequantize_rows(vq, vs_n, q_ref.dtype)
         s = jax.lax.dot_general(
             q, kn, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
         if softcap > 0:
             s = softcap * jnp.tanh(s / softcap)
         kpos = off + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _online_update(s, kpos, vn)
+        _online_update(s, kpos, vn, 0)
 
         l = l_ref[...]
         o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-        if quantized:
-            ko_ref[0] = kn_q
-            vo_ref[0] = vn_q
-            kso_ref[0] = C.splice_row(ksw_ref[0], ks_n, off % sw)
-            vso_ref[0] = C.splice_row(vsw_ref[0], vs_n, off % sw)
-        else:
-            ko_ref[0] = kn_ref[0].astype(ko_ref.dtype)
-            vo_ref[0] = vn_ref[0].astype(vo_ref.dtype)
 
 
-def _call(q, k_new, v_new, k_cache, v_cache, offset, scales, *,
+def _call(q, k_new, v_new, k_cache, v_cache, offset, layer, scales, *,
           bkv, window, softcap, scale, prefix_limit, interpret):
     """Shared pallas_call builder for the dense and int8-cache paths.
 
-    ``scales`` is ``None`` (dense) or ``(k_scale, v_scale)`` — [B*HK, 1, M]
-    f32 per-row side arrays, aliased to outputs just like the caches. Their
-    chunk append goes through a (1, 1, sw) window, sw = 128 for chunks
-    narrower than the TPU's 128-lane tile: the window's old scales ride in
-    as an input block and the chunk's are spliced in at lane ``off % sw``."""
+    ``k_cache``/``v_cache`` are [L*B*HK, D, M]: every layer's cache,
+    stacked, and seen transposed — the layout a TPU holds a ``[.., M, D]``
+    cache in when D is under 128 lanes, so the view is free — of which
+    ``layer`` ([1] int32, a second scalar-prefetch operand) is read: the
+    index maps offset each row by ``layer * B*HK``. A single layer's cache
+    is the case L = 1, ``layer`` 0. The kernel only reads the cache; the
+    chunk's rows, ``k_new``/``v_new`` [B*HK, C, D], ride in as operands.
+    ``scales`` is ``None`` (dense) or ``(k_scale, v_scale)``, the cache's
+    [L*B*HK, 1, M] f32 per-row scales of an int8 cache; the call then also
+    returns the chunk's rows quantized, ``(out, k_q, v_q, k_q_scale,
+    v_q_scale)`` ([B*HK, C, D] int8, [B*HK, C, 1] f32), for the cache."""
     bhk, gc, d = q.shape
     c = k_new.shape[1]
-    m = k_cache.shape[1]
+    m = k_cache.shape[2]
     b = offset.shape[0]
     hk = bhk // b
     assert m % bkv == 0, (m, bkv)
-    assert m % c == 0 and gc % c == 0, (m, gc, c)
+    assert gc % c == 0, (gc, c)
     scale = scale if scale is not None else 1.0 / d**0.5
     nkv = m // bkv
     quantized = scales is not None
-    sw = 128 if c < 128 and m % 128 == 0 else c
 
     kern = functools.partial(
         _kernel, scale=scale, bkv=bkv, c=c, window=window, softcap=softcap,
         nkv=nkv, hk=hk, prefix_limit=prefix_limit, quantized=quantized,
-        sw=sw,
     )
 
     def live_j(bh, j, off_ref):
@@ -198,54 +197,33 @@ def _call(q, k_new, v_new, k_cache, v_cache, offset, scales, *,
         lo = jnp.maximum(off - window, 0) // bkv if window > 0 else 0
         return jnp.clip(j, lo, hi)
 
-    def kv_index(bh, j, off_ref):
-        return (bh, live_j(bh, j, off_ref), 0)
+    def kv_index(bh, j, off_ref, layer_ref):
+        # k/v blocks and their scales: the same clamped schedule
+        return (layer_ref[0] * bhk + bh, 0, live_j(bh, j, off_ref))
 
-    def scale_index(bh, j, off_ref):
-        return (bh, 0, live_j(bh, j, off_ref))
-
-    def chunk_out_index(bh, j, off_ref):
-        return (bh, off_ref[bh // hk] // c, 0)
-
-    def scale_window_index(bh, j, off_ref):
-        return (bh, 0, off_ref[bh // hk] // sw)
+    def slot_index(bh, j, off_ref, layer_ref):
+        return (bh, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, gc, d), lambda bh, j, off_ref: (bh, 0, 0)),
-        pl.BlockSpec((1, c, d), lambda bh, j, off_ref: (bh, 0, 0)),
-        pl.BlockSpec((1, c, d), lambda bh, j, off_ref: (bh, 0, 0)),
-        pl.BlockSpec((1, bkv, d), kv_index),
-        pl.BlockSpec((1, bkv, d), kv_index),
+        pl.BlockSpec((1, gc, d), slot_index),
+        pl.BlockSpec((1, c, d), slot_index),
+        pl.BlockSpec((1, c, d), slot_index),
+        pl.BlockSpec((1, d, bkv), kv_index),
+        pl.BlockSpec((1, d, bkv), kv_index),
     ]
-    out_specs = [
-        pl.BlockSpec((1, gc, d), lambda bh, j, off_ref: (bh, 0, 0)),
-        pl.BlockSpec((1, c, d), chunk_out_index),
-        pl.BlockSpec((1, c, d), chunk_out_index),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((bhk, gc, d), q.dtype),
-        jax.ShapeDtypeStruct((bhk, m, d), k_cache.dtype),
-        jax.ShapeDtypeStruct((bhk, m, d), v_cache.dtype),
-    ]
-    operands = [offset, q, k_new, v_new, k_cache, v_cache]
-    # cache operands alias their outputs: the only blocks written back are
-    # the (1, C, D) chunk windows (and, quantized, the (1, 1, sw) scale
-    # windows) — the rest of the cache stays resident.
-    aliases = {4: 1, 5: 2}
+    operands = [offset, layer, q, k_new, v_new, k_cache, v_cache]
+    out_specs = [pl.BlockSpec((1, gc, d), slot_index)]
+    out_shape = [jax.ShapeDtypeStruct((bhk, gc, d), q.dtype)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, bkv), scale_index),
-                     pl.BlockSpec((1, 1, bkv), scale_index),
-                     pl.BlockSpec((1, 1, sw), scale_window_index),
-                     pl.BlockSpec((1, 1, sw), scale_window_index)]
-        out_specs += [pl.BlockSpec((1, 1, sw), scale_window_index),
-                      pl.BlockSpec((1, 1, sw), scale_window_index)]
-        out_shape += [jax.ShapeDtypeStruct((bhk, 1, m), jnp.float32),
-                      jax.ShapeDtypeStruct((bhk, 1, m), jnp.float32)]
-        operands += list(scales) * 2
-        aliases.update({8: 3, 9: 4})
+        in_specs += [pl.BlockSpec((1, 1, bkv), kv_index)] * 2
+        operands += list(scales)
+        out_specs += [pl.BlockSpec((1, c, d), slot_index)] * 2
+        out_specs += [pl.BlockSpec((1, c, 1), slot_index)] * 2
+        out_shape += [jax.ShapeDtypeStruct((bhk, c, d), jnp.int8)] * 2
+        out_shape += [jax.ShapeDtypeStruct((bhk, c, 1), jnp.float32)] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(bhk, nkv + 1),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -255,13 +233,13 @@ def _call(q, k_new, v_new, k_cache, v_cache, offset, scales, *,
             pltpu.VMEM((gc,), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        input_output_aliases=aliases,
         interpret=interpret,
     )(*operands)
+    return tuple(outs) if quantized else outs[0]
 
 
 def _paged_kernel(
@@ -543,11 +521,12 @@ def prefill_append_paged_kernel_quant(
 )
 def prefill_append_kernel(
     q: jax.Array,        # [B*HK, G*C, D] grouped chunk queries
-    k_new: jax.Array,    # [B*HK, C, D] chunk keys (to append)
+    k_new: jax.Array,    # [B*HK, C, D] chunk keys
     v_new: jax.Array,    # [B*HK, C, D]
-    k_cache: jax.Array,  # [B*HK, M, D] batched cache (M a bkv multiple)
-    v_cache: jax.Array,  # [B*HK, M, D]
-    offset: jax.Array,   # [B] int32 per-slot frontier / write base (≡ 0 mod C)
+    k_cache: jax.Array,  # [L*B*HK, D, M] stacked cache, transposed
+    v_cache: jax.Array,  # [L*B*HK, D, M]
+    offset: jax.Array,   # [B] int32 per-slot frontier (≡ 0 mod C)
+    layer: jax.Array,    # [1] int32 layer of the stack to read
     *,
     bkv: int = 128,
     window: int = 0,
@@ -555,8 +534,9 @@ def prefill_append_kernel(
     scale: float | None = None,
     prefix_limit: int = 0,  # >0: offsets past it are write-only (no prefix scan)
     interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    return _call(q, k_new, v_new, k_cache, v_cache, offset, None,
+) -> jax.Array:
+    """Chunk attention over the cache prefix and the chunk: [B*HK, G*C, D]."""
+    return _call(q, k_new, v_new, k_cache, v_cache, offset, layer, None,
                  bkv=bkv, window=window, softcap=softcap, scale=scale,
                  prefix_limit=prefix_limit, interpret=interpret)
 
@@ -566,14 +546,15 @@ def prefill_append_kernel(
                               "prefix_limit", "interpret")
 )
 def prefill_append_kernel_quant(
-    q: jax.Array,        # [B*HK, G*C, D] grouped chunk queries
-    k_new: jax.Array,    # [B*HK, C, D] chunk keys (float; quantized in VMEM)
-    v_new: jax.Array,    # [B*HK, C, D]
-    k_cache: jax.Array,  # [B*HK, M, D] int8 cache
-    v_cache: jax.Array,  # [B*HK, M, D] int8 cache
-    k_scale: jax.Array,  # [B*HK, 1, M] f32 per-row scales
-    v_scale: jax.Array,  # [B*HK, 1, M]
-    offset: jax.Array,   # [B] int32 per-slot frontier / write base (≡ 0 mod C)
+    q: jax.Array,            # [B*HK, G*C, D] grouped chunk queries
+    k_new: jax.Array,        # [B*HK, C, D] chunk keys (float; quantized in VMEM)
+    v_new: jax.Array,        # [B*HK, C, D]
+    k_cache: jax.Array,      # [L*B*HK, D, M] stacked int8 cache, transposed
+    v_cache: jax.Array,      # [L*B*HK, D, M]
+    k_scale: jax.Array,      # [L*B*HK, 1, M] f32 per-row scales
+    v_scale: jax.Array,      # [L*B*HK, 1, M]
+    offset: jax.Array,       # [B] int32 per-slot frontier (≡ 0 mod C)
+    layer: jax.Array,        # [1] int32 layer of the stack to read
     *,
     bkv: int = 128,
     window: int = 0,
@@ -581,10 +562,11 @@ def prefill_append_kernel_quant(
     scale: float | None = None,
     prefix_limit: int = 0,
     interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> tuple[jax.Array, ...]:
     """Int8-cache twin of :func:`prefill_append_kernel`: prefix blocks are
-    dequantized in VMEM, the chunk's rows are absmax-quantized in VMEM before
-    the aliased append. Returns (out, k_cache', v_cache', k_scale', v_scale')."""
-    return _call(q, k_new, v_new, k_cache, v_cache, offset,
-                 (k_scale, v_scale), bkv=bkv, window=window, softcap=softcap,
-                 scale=scale, prefix_limit=prefix_limit, interpret=interpret)
+    dequantized in VMEM, the chunk's rows absmax-quantized there. Returns
+    (out, k_q, v_q, k_q_scale, v_q_scale), the chunk's rows for the cache."""
+    return _call(q, k_new, v_new, k_cache, v_cache, offset, layer,
+                 (k_scale, v_scale), bkv=bkv,
+                 window=window, softcap=softcap, scale=scale,
+                 prefix_limit=prefix_limit, interpret=interpret)

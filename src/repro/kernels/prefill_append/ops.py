@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .. import _common as C
@@ -9,6 +10,20 @@ from .. import autotune
 from .kernel import (prefill_append_kernel, prefill_append_kernel_quant,
                      prefill_append_paged_kernel,
                      prefill_append_paged_kernel_quant)
+
+
+def _write_chunk(cache, rows, offset, layer, axis):
+    """Each slot's chunk rows into ``cache`` [L, B, HK, X, Y] at
+    ``[layer, b, :, ...]``, starting at ``offset[b]`` on ``axis``: one
+    ``dynamic_update_slice`` per slot, in place on whatever layout the cache
+    is held in. ``rows`` [B, HK, ...] match the cache's trailing axes."""
+    zero = jnp.int32(0)
+    for i in range(rows.shape[0]):
+        start = [layer, jnp.int32(i), zero, zero, zero]
+        start[axis] = offset[i]
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[i][None, None].astype(cache.dtype), start)
+    return cache
 
 
 def prefill_append(
@@ -21,6 +36,7 @@ def prefill_append(
     *,
     k_scale: jax.Array | None = None,  # [B, HK, M] f32 (int8 cache only)
     v_scale: jax.Array | None = None,
+    layer: jax.Array | int | None = None,
     window: int = 0,
     softcap: float = 0.0,
     scale: float | None = None,
@@ -32,66 +48,71 @@ def prefill_append(
 
     Returns (out [B, H, C, D], k_cache', v_cache') — with ``k_scale`` /
     ``v_scale`` set (int8 cache, DESIGN.md §kv-cache) the tuple grows to
-    (out, k_cache', v_cache', k_scale', v_scale'): the chunk's rows are
-    quantized in VMEM at append time and the scale side arrays updated through
-    their own aliased chunk windows. The cache length M must be a multiple of
-    the chunk size C (the engine pads ``max_len`` accordingly); ``bkv`` is
-    halved until it divides M so unaligned smoke caches still run.
+    (out, k_cache', v_cache', k_scale', v_scale'): the kernel absmax-quantizes
+    the chunk's rows in VMEM, attends to the dequantized quantized rows, as
+    every later reader will, and hands them back for the cache. The kernel
+    reads the prefix transposed, ``[.., D, M]``, the layout a TPU holds a
+    ``[.., M, D]`` cache in when D is under 128 lanes, so the view moves
+    nothing there; then the chunk's rows land in the cache in place, one
+    update per slot and leaf. ``bkv`` is the largest multiple of 128 that
+    divides M, or M whole (the cache is not padded).
     ``prefix_limit > 0`` marks offsets at/past it as *write-only* (the
     engine's trash-diverted slots): their prefix blocks all go dead instead
     of streaming the whole cache for an output nobody reads.
+
+    With ``layer`` set the caches are every layer's, stacked (DESIGN.md
+    §decode): k/v [L, B, HK, M, D], scales [L, B, HK, 1, M]. The chunk is
+    written to and the prefix read from layer ``layer``, and the returned
+    caches are the whole stack.
     """
     interpret = C.resolve_interpret(interpret)
     b, h, c, d = q.shape
-    hk, m = k_cache.shape[1], k_cache.shape[2]
+    hk, m = k_cache.shape[-3], k_cache.shape[-2]
     g = h // hk
     offset = jnp.broadcast_to(jnp.asarray(offset, jnp.int32), (b,))
+    rows = k_cache.size // (m * d)  # L*B*HK
+    li = jnp.asarray(0 if layer is None else layer, jnp.int32)
+    quantized = k_scale is not None
 
     if bkv is None:
         bkv = autotune.best(
             "prefill_append",
             autotune.shape_key(b=b, c=c, d=d, h=h, hk=hk, s=m),
             {"bkv": 128})["bkv"]
-    bkv = min(bkv, m)
-    while m % bkv:
-        bkv //= 2
+    bkv = C.lane_block(m, bkv)
 
-    qg = q.reshape(b, hk, g, c, d).reshape(b * hk, g * c, d)
-    if k_scale is not None:
-        out, k_cache, v_cache, k_scale, v_scale = prefill_append_kernel_quant(
-            qg,
-            k_new.reshape(b * hk, c, d),
-            v_new.reshape(b * hk, c, d),
-            k_cache.reshape(b * hk, m, d),
-            v_cache.reshape(b * hk, m, d),
-            k_scale.reshape(b * hk, 1, m).astype(jnp.float32),
-            v_scale.reshape(b * hk, 1, m).astype(jnp.float32),
-            offset,
-            bkv=bkv, window=window, softcap=softcap, scale=scale,
-            prefix_limit=prefix_limit, interpret=interpret,
-        )
-        return (
-            out.reshape(b, hk, g, c, d).reshape(b, h, c, d),
-            k_cache.reshape(b, hk, m, d),
-            v_cache.reshape(b, hk, m, d),
-            k_scale.reshape(b, hk, m),
-            v_scale.reshape(b, hk, m),
-        )
-    out, k_cache, v_cache = prefill_append_kernel(
-        qg,
-        k_new.reshape(b * hk, c, d),
-        v_new.reshape(b * hk, c, d),
-        k_cache.reshape(b * hk, m, d),
-        v_cache.reshape(b * hk, m, d),
-        offset,
-        bkv=bkv, window=window, softcap=softcap, scale=scale,
-        prefix_limit=prefix_limit, interpret=interpret,
-    )
-    return (
-        out.reshape(b, hk, g, c, d).reshape(b, h, c, d),
-        k_cache.reshape(b, hk, m, d),
-        v_cache.reshape(b, hk, m, d),
-    )
+    # every leaf as [L, B, HK, X, Y], the chunk written along X (data) or Y
+    kc = k_cache.reshape(-1, b, hk, m, d)
+    vc = v_cache.reshape(-1, b, hk, m, d)
+
+    def stack_t(x):  # [L, B, HK, M, D] -> [L*B*HK, D, M]
+        return jnp.swapaxes(x, -1, -2).reshape(rows, d, m)
+
+    args = (q.reshape(b, hk, g, c, d).reshape(b * hk, g * c, d),
+            k_new.reshape(b * hk, c, d), v_new.reshape(b * hk, c, d),
+            stack_t(kc), stack_t(vc))
+    kw = dict(bkv=bkv, window=window, softcap=softcap, scale=scale,
+              prefix_limit=prefix_limit, interpret=interpret)
+    layer_op = li.reshape(1)
+    # the kernel reads the prefix; the chunk's rows then land in place
+    if quantized:
+        ksc = k_scale.reshape(-1, b, hk, 1, m)
+        vsc = v_scale.reshape(-1, b, hk, 1, m)
+        out, kq, vq, ksq, vsq = prefill_append_kernel_quant(
+            *args, ksc.reshape(rows, 1, m), vsc.reshape(rows, 1, m), offset,
+            layer_op, **kw)
+        kn, vn = kq.reshape(b, hk, c, d), vq.reshape(b, hk, c, d)
+        ksc = _write_chunk(ksc, ksq.reshape(b, hk, 1, c), offset, li, 4)
+        vsc = _write_chunk(vsc, vsq.reshape(b, hk, 1, c), offset, li, 4)
+    else:
+        out = prefill_append_kernel(*args, offset, layer_op, **kw)
+        kn, vn = k_new, v_new
+    kc = _write_chunk(kc, kn, offset, li, 3)
+    vc = _write_chunk(vc, vn, offset, li, 3)
+    caches = (kc.reshape(k_cache.shape), vc.reshape(v_cache.shape))
+    if quantized:
+        caches += (ksc.reshape(k_scale.shape), vsc.reshape(v_scale.shape))
+    return (out.reshape(b, hk, g, c, d).reshape(b, h, c, d),) + caches
 
 
 def prefill_append_paged(

@@ -152,7 +152,10 @@ def decode_attention(
     softcap: float = 0.0,
     scale: float | None = None,
     impl: str = "auto",
-) -> jax.Array:
+    layer: jax.Array | None = None,
+    k_new: jax.Array | None = None,
+    v_new: jax.Array | None = None,
+):
     """Single-token attention against the KV cache.
 
     ``impl`` selects the execution path:
@@ -167,15 +170,25 @@ def decode_attention(
     §kv-cache): the kernel dequantizes per VMEM block; the XLA form
     dequantizes the whole cache up front — dense compute either way, so the
     materialization is the documented fallback cost, not the serving path.
+
+    ``layer`` and ``k_new``/``v_new`` (kernel only, DESIGN.md §decode):
+    read one layer of the stacked cache the layer loop carries (k/v
+    [L, B, HK, M, D], scales [L, B, HK, 1, M]), and write each slot's new
+    row [B, HK, D] at ``pos`` in place first; the call then returns
+    ``(out, k_cache, v_cache[, k_scale, v_scale])``.
     """
     if impl == "auto":
         impl = "kernel" if jax.default_backend() == "tpu" else "xla"
+    if (layer is not None or k_new is not None) and impl != "kernel":
+        raise ValueError("decode_attention: the stacked cache (layer=...) "
+                         "and the in-place row write are the kernel's only")
     if impl == "kernel":
         from ..kernels.decode_attention import ops as da_ops
 
         return da_ops.decode_attention(
             q, k_cache, v_cache, pos, k_scale=k_scale, v_scale=v_scale,
-            window=window, softcap=softcap, scale=scale
+            layer=layer, k_new=k_new, v_new=v_new, window=window,
+            softcap=softcap, scale=scale
         )
     if k_scale is not None:
         k_cache = ternary.dequantize_kv(k_cache, k_scale, q.dtype)
@@ -224,6 +237,7 @@ def prefill_append_attention(
     impl: str = "auto",
     prefix_limit: int = 0,
     aligned: bool = True,
+    layer: jax.Array | None = None,
 ):
     """Chunked prefill against a cache prefix (the ``mode="prefill_chunk"`` path).
 
@@ -238,8 +252,8 @@ def prefill_append_attention(
 
     ``impl`` selects the execution path:
       * ``"kernel"`` — the fused Pallas kernel (kernels/prefill_append):
-        frontier-skipped prefix blocks + in-place chunk append through aliased
-        output windows, so skipped cache blocks move no HBM traffic;
+        frontier-skipped prefix blocks, so skipped cache blocks move no HBM
+        traffic, and the chunk appended in place (DESIGN.md §decode);
       * ``"xla"``    — this module's dense form over the full padded cache
         (the interpret/CPU fallback and the dry-run lowering);
       * ``"auto"``   — kernel on TPU, XLA elsewhere.
@@ -250,26 +264,33 @@ def prefill_append_attention(
     rows' outputs are garbage by contract (their rows still quantize exactly
     like live ones, so the trash tail keeps the same int8+scale layout).
 
-    ``aligned`` declares the caller's offset contract: the kernel's aliased
-    cache-append windows require ``offset ≡ 0 (mod C)`` (the engine's chunk
-    schedule guarantees it); speculative verify chunks land at *arbitrary*
+    ``aligned`` declares the caller's offset contract: the kernel path is held
+    to ``offset ≡ 0 (mod C)``, which the paged kernel's aliased page windows
+    need (the engine's chunk schedule guarantees it); speculative verify
+    chunks land at *arbitrary*
     decode frontiers and pass ``aligned=False``, which pins ``"auto"`` to the
     XLA form (its masked-select append handles any offset) and rejects an
     explicit ``"kernel"`` rather than mis-writing the cache.
+
+    ``layer`` (kernel only) appends to one layer of the stacked cache the
+    layer loop carries (DESIGN.md §decode) and returns the whole stack.
     """
     if impl == "auto":
         impl = "kernel" if aligned and jax.default_backend() == "tpu" else "xla"
     if impl == "kernel" and not aligned:
         raise ValueError(
             "prefill_append_attention: impl='kernel' requires chunk-aligned "
-            "offsets (aligned=True) — the aliased cache windows write at "
-            "offset/C; speculative verify frontiers are arbitrary")
+            "offsets (aligned=True); speculative verify frontiers are "
+            "arbitrary")
+    if layer is not None and impl != "kernel":
+        raise ValueError("prefill_append_attention: a stacked cache "
+                         "(layer=...) is appended to by the kernel only")
     if impl == "kernel":
         from ..kernels.prefill_append import ops as pa_ops
 
         return pa_ops.prefill_append(
             q, k_new, v_new, k_cache, v_cache, offset,
-            k_scale=k_scale, v_scale=v_scale,
+            k_scale=k_scale, v_scale=v_scale, layer=layer,
             window=window, softcap=softcap, scale=scale,
             prefix_limit=prefix_limit,
         )
@@ -426,8 +447,8 @@ def append_kv_cache(k_cache, v_cache, k_new, v_new, offset):
     Per-slot ``offset [B]`` uses a gather + masked select on the seq axis —
     full-cache elementwise like ``update_kv_cache``'s one-hot form, but
     sharding-safe (no dynamic scatter, which would defeat GSPMD sharding of
-    the cache). The Pallas kernel path never calls this: it stores the chunk
-    through aliased output windows instead.
+    the cache). The kernel path never calls this: it appends the chunk in
+    place (DESIGN.md §decode).
     """
     b, hk, m, d = k_cache.shape
     c = k_new.shape[2]
@@ -503,14 +524,18 @@ def update_kv_cache_quant(k_cache, v_cache, k_scale, v_scale, k_new, v_new,
 def update_kv_cache(k_cache, v_cache, k_new, v_new, pos):
     """Write the new token's K/V at ``pos``. k_new [B, HK, D].
 
-    Two forms:
+    The rewrite form, for the XLA attention path (the CPU, ``attn_impl=
+    "xla"``, the GSPMD dry-run lowerings) and the scanned per-layer caches it
+    reads; the kernel path writes rows in place instead (the decode kernel's
+    ``k_new``, DESIGN.md §decode). Two forms:
     * scalar ``pos`` (synchronized decode, the decode_* dry-run shapes):
       ``dynamic_update_slice`` on the seq axis — slice-sized traffic, shards
       cleanly under GSPMD;
-    * per-batch ``pos [B]`` (continuous batching, heterogeneous slots):
-      one-hot masked write — full-cache elementwise, but sharding-safe.
-      A per-batch *scatter* is never used: dynamic scatter indices defeat
-      GSPMD sharding of the cache (it would all-gather it per layer).
+    * per-batch ``pos [B]`` (heterogeneous slots off the kernel path):
+      one-hot masked write — it rewrites the whole layer cache, but is
+      sharding-safe. A per-batch *scatter* is not used here: dynamic scatter
+      indices defeat GSPMD sharding of the cache (it would all-gather it per
+      layer).
     """
     pos = jnp.asarray(pos)
     if pos.ndim == 0:
@@ -527,3 +552,4 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, pos):
     k_cache = k_cache * (1 - ohk) + k_new[:, :, None, :].astype(k_cache.dtype) * ohk
     v_cache = v_cache * (1 - ohk) + v_new[:, :, None, :].astype(v_cache.dtype) * ohk
     return k_cache, v_cache
+

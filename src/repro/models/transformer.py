@@ -210,7 +210,8 @@ def pack_tree(params, specs):
 
 def _apply_attn(bp, x, cfg, kind, positions, *, mode, cache=None, pos=None,
                 attn_impl="auto", prefix_limit=0, aligned=True, rope=None,
-                xq=None, residual=None, use_kernel="auto", page_table=None):
+                xq=None, residual=None, use_kernel="auto", page_table=None,
+                layer=None):
     """``xq`` (the fused norm-quant prologue's ``(x_i8, x_scale[, tables])``)
     replaces ``x`` as the projection input on the int8-resident path;
     ``residual`` is folded into the o-projection's dequant epilogue. ``rope``
@@ -220,7 +221,10 @@ def _apply_attn(bp, x, cfg, kind, positions, *, mode, cache=None, pos=None,
     engine selector threaded from ``cfg.matmul_engine`` on the packed path
     (``bitlinear.apply``'s TL-vs-packed dispatch). ``page_table`` ([B, NB]
     int32, DESIGN.md §paged-kv) switches the cache leaves' interpretation to
-    page pools and routes reads/writes through the page-indirect forms."""
+    page pools and routes reads/writes through the page-indirect forms.
+    ``layer`` (DESIGN.md §decode) marks ``cache`` as every layer's, stacked
+    and carried through the layer loop: this layer's new rows are written
+    in place and the kernels read the layer from the stack."""
     b, s, _ = x.shape
     h, hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     window = cfg.sliding_window if kind.local else 0
@@ -252,7 +256,24 @@ def _apply_attn(bp, x, cfg, kind, positions, *, mode, cache=None, pos=None,
     quant = cfg.kv_cache_dtype == "int8" and mode != "train"
     if page_table is not None and cache is None:
         raise ValueError("paged attention requires an existing cache pool")
-    if cache is None:  # prefill / train
+    if layer is not None:  # in place on the stacked cache (kv_in_place)
+        scales = ({"k_scale": cache["k_scale"], "v_scale": cache["v_scale"]}
+                  if quant else {})
+        if s > 1:
+            out, *kv = attn_ops.prefill_append_attention(
+                q, k, v, cache["k"], cache["v"], pos, **scales,
+                window=window, softcap=cfg.attn_logit_softcap, impl="kernel",
+                prefix_limit=prefix_limit, layer=layer,
+            )
+        else:
+            out, *kv = attn_ops.decode_attention(
+                q[:, :, 0], cache["k"], cache["v"], pos, **scales,
+                window=window, softcap=cfg.attn_logit_softcap, impl="kernel",
+                layer=layer, k_new=k[:, :, 0], v_new=v[:, :, 0],
+            )
+            out = out[:, :, None, :].transpose(0, 2, 1, 3)
+        new_cache = dict(zip(("k", "v", "k_scale", "v_scale"), kv))
+    elif cache is None:  # prefill / train
         if quant:
             # quantize-then-attend: one-shot prefill sees the same
             # dequantized rows every later reader (and the chunked prefill
@@ -379,8 +400,11 @@ def _apply_ffn(fp, x, cfg, kind, pcfg, *, mode):
 
 def apply_block(kind: LayerKind, bp, x, cfg, pcfg, positions, *, mode, cache=None,
                 pos=None, attn_impl="auto", prefix_limit=0, aligned=True,
-                rope=None, fused=None, page_table=None):
+                rope=None, fused=None, page_table=None, layer=None):
     """Returns (x, new_cache, aux).
+
+    ``layer`` marks an attention mixer's ``cache`` as the stacked carry of
+    the in-place path (:func:`kv_in_place`).
 
     ``rope`` is the step's precomputed table dict from :func:`rope_for`
     (per-mixer (cos, sin); built lazily when absent). ``fused`` routes
@@ -443,7 +467,8 @@ def apply_block(kind: LayerKind, bp, x, cfg, pcfg, positions, *, mode, cache=Non
                                    cache=cache, pos=pos, attn_impl=attn_impl,
                                    prefix_limit=prefix_limit, aligned=aligned,
                                    rope=rope.get("attn"), xq=hq, residual=x,
-                                   use_kernel=engine, page_table=page_table)
+                                   use_kernel=engine, page_table=page_table,
+                                   layer=layer)
         x = constrain(x, "act_batch", "act_seq", None)
         t2 = bitlinear.resolve_engine(bp["ffn"]["gate"], rows,
                                       use_kernel=engine) == "tl"
@@ -459,7 +484,8 @@ def apply_block(kind: LayerKind, bp, x, cfg, pcfg, positions, *, mode, cache=Non
         y, new_cache = _apply_attn(bp["attn"], h, cfg, kind, positions, mode=mode,
                                    cache=cache, pos=pos, attn_impl=attn_impl,
                                    prefix_limit=prefix_limit, aligned=aligned,
-                                   rope=rope.get("attn"), page_table=page_table)
+                                   rope=rope.get("attn"), page_table=page_table,
+                                   layer=layer)
     elif kind.mixer == "mla":
         if cache is None:
             y, new_cache = mla_mod.mla_prefill(bp["attn"], h, cfg, positions, mode=mode,
@@ -567,6 +593,62 @@ def loss_fn(params, batch, cfg, pcfg=None, *, mode="train", aux_weight=0.01):
     return ce + aux_weight * aux, {"ce": ce, "aux": aux}
 
 
+def kv_in_place(attn_impl: str, *, paged: bool = False,
+                aligned: bool = True) -> bool:
+    """Whether a cached step writes the attention KV cache in place
+    (DESIGN.md §decode): the stacked cache rides the layer loop's carry,
+    each layer writes only its new rows into it, and the Pallas kernels read
+    the layer from the stack through their index maps — nothing the size of
+    the cache is sliced out, rewritten or restacked.
+
+    It needs the kernels: the XLA form (the CPU, ``attn_impl="xla"``, the
+    GSPMD dry-run lowerings) keeps the scan's per-layer slices and the
+    sharding-safe rewrite (``attn_ops.update_kv_cache``), as per-slot
+    scatter indices would all-gather a sharded cache. The paged pools and
+    the unaligned speculative verify chunks keep the rewrite too."""
+    if attn_impl == "auto":
+        attn_impl = "kernel" if jax.default_backend() == "tpu" else "xla"
+    return attn_impl == "kernel" and not paged and aligned
+
+
+def _layer_loop(period, n_periods, block, x, params_blocks, caches_blocks,
+                in_place):
+    """Scan the period over the layer stack; returns (x, new block caches).
+
+    ``block(kind, bp, x, cache, layer) -> (x, cache)`` applies one block.
+    Caches ride the scan as ``xs`` -> ``ys``, one layer's slice per step —
+    except, ``in_place``, the attention mixers' caches: those ride the carry
+    whole and each step gets ``layer``. Their int8 scale leaves take the
+    kernels' [L, B, HK, 1, M] form for the loop (one relayout each way per
+    step, as the kernels read a (1, bkv) scale block)."""
+    carried = {f"b{i}" for i, k in enumerate(period)
+               if in_place and k.mixer == "attn"}
+
+    def to_carry(c, fn):
+        return {n: fn(a) if n.endswith("_scale") else a for n, a in c.items()}
+
+    kv = {n: to_carry(caches_blocks[n], lambda a: a[..., None, :])
+          for n in carried}
+    scanned = {n: c for n, c in caches_blocks.items() if n not in carried}
+
+    def body(carry, xs):
+        x, kv = carry
+        pparams, pcaches, layer = xs
+        kv, cs = dict(kv), {}
+        for i, kind in enumerate(period):
+            n = f"b{i}"
+            if n in carried:
+                x, kv[n] = block(kind, pparams[n], x, kv[n], layer)
+            else:
+                x, cs[n] = block(kind, pparams[n], x, pcaches[n], None)
+        return (x, kv), cs
+
+    layers = jnp.arange(n_periods, dtype=jnp.int32)
+    (x, kv), cs = jax.lax.scan(body, (x, kv), (params_blocks, scanned, layers))
+    cs.update({n: to_carry(c, lambda a: a[..., 0, :]) for n, c in kv.items()})
+    return x, cs
+
+
 def decode_step(params, batch, caches, pos, cfg, *, mode="eval", attn_impl="auto",
                 fused=None, page_table=None):
     """One autoregressive step. batch {tokens [B,1] | embeddings [B,1,Dfe]};
@@ -596,20 +678,17 @@ def decode_step(params, batch, caches, pos, cfg, *, mode="eval", attn_impl="auto
                               page_table=page_table)
         new_caches[f"prelude_{i}"] = c
 
-    def body(carry, xs):
-        x = carry
-        pparams, pcaches = xs
-        cs = {}
-        for i, kind in enumerate(period):
-            x, c, _ = apply_block(kind, pparams[f"b{i}"], x, cfg, None, positions,
-                                  mode=mode, cache=pcaches[f"b{i}"], pos=pos,
-                                  attn_impl=attn_impl, rope=rope, fused=fused,
-                                  page_table=page_table)
-            cs[f"b{i}"] = c
-        return x, cs
+    def block(kind, bp, x, cache, layer):
+        x, c, _ = apply_block(kind, bp, x, cfg, None, positions, mode=mode,
+                              cache=cache, pos=pos, attn_impl=attn_impl,
+                              rope=rope, fused=fused, page_table=page_table,
+                              layer=layer)
+        return x, c
 
-    x, blk_caches = jax.lax.scan(body, x, (params["blocks"], caches["blocks"]))
-    new_caches["blocks"] = blk_caches
+    in_place = kv_in_place(attn_impl, paged=page_table is not None)
+    x, new_caches["blocks"] = _layer_loop(period, n_periods, block, x,
+                                          params["blocks"], caches["blocks"],
+                                          in_place)
 
     with jax.named_scope("head"):
         x = L.rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
@@ -653,21 +732,19 @@ def prefill_chunk_step(params, batch, caches, offset, cfg, *, mode="eval",
                                 page_table=page_table)
         new_caches[f"prelude_{i}"] = cch
 
-    def body(carry, xs):
-        x = carry
-        pparams, pcaches = xs
-        cs = {}
-        for i, kind in enumerate(period):
-            x, cch, _ = apply_block(kind, pparams[f"b{i}"], x, cfg, None, positions,
-                                    mode=mode, cache=pcaches[f"b{i}"], pos=offset,
-                                    attn_impl=attn_impl, prefix_limit=prefix_limit,
-                                    aligned=aligned, rope=rope, fused=fused,
-                                    page_table=page_table)
-            cs[f"b{i}"] = cch
-        return x, cs
+    def block(kind, bp, x, cache, layer):
+        x, c, _ = apply_block(kind, bp, x, cfg, None, positions, mode=mode,
+                              cache=cache, pos=offset, attn_impl=attn_impl,
+                              prefix_limit=prefix_limit, aligned=aligned,
+                              rope=rope, fused=fused, page_table=page_table,
+                              layer=layer)
+        return x, c
 
-    x, blk_caches = jax.lax.scan(body, x, (params["blocks"], caches["blocks"]))
-    new_caches["blocks"] = blk_caches
+    in_place = kv_in_place(attn_impl, paged=page_table is not None,
+                           aligned=aligned)
+    x, new_caches["blocks"] = _layer_loop(period, n_periods, block, x,
+                                          params["blocks"], caches["blocks"],
+                                          in_place)
 
     with jax.named_scope("head"):
         if last_row is not None:
@@ -704,9 +781,9 @@ def verify_chunk_step(params, batch, caches, offset, cfg, *, mode="eval",
     dead to every subsequent read and overwritten by the next tick's chunk
     (see ``core.ternary.mask_past_frontier`` for the invariant).
 
-    ``attn_impl``: the Pallas ``prefill_append`` kernel stores chunks through
-    aliased cache windows at ``offset/C`` and therefore *requires*
-    chunk-aligned frontiers — verify offsets are not — so this step threads
+    ``attn_impl``: the kernel path of ``prefill_append`` is held to
+    chunk-aligned frontiers (the paged kernel's aliased page windows need
+    them) — verify offsets are not — so this step threads
     ``aligned=False`` down to ``prefill_append_attention``, which resolves
     ``"auto"`` to the XLA append form even on TPU and rejects an explicit
     ``"kernel"`` rather than mis-writing the cache (a frontier-aligned

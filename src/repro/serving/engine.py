@@ -704,6 +704,16 @@ class ServingEngine:
                     else jax.default_backend())
         return "kernel" if platform == "tpu" else "xla"
 
+    @property
+    def kv_write(self) -> str:
+        """How the ticks write the KV cache (DESIGN.md §decode):
+        ``"in_place"`` — rows written into the stacked cache the layer loop
+        carries — where the attention runs on the kernels over a contiguous
+        cache, else ``"rewrite"``."""
+        return ("in_place" if Tr.kv_in_place(self.attn_path,
+                                             paged=self.paged is not None)
+                else "rewrite")
+
     def submit(self, req: Request) -> bool:
         """Enqueue ``req``; returns False (terminal ``FAILED``,
         ``status_detail="queue_full"``) when the bounded admission queue is
@@ -873,6 +883,7 @@ class ServingEngine:
                                if e["kind"] == "preempt"),
             "quarantined": self.status_counts.get(R.Status.QUARANTINED, 0),
             "kv_layout": "paged" if self.paged is not None else "contiguous",
+            "kv_write": self.kv_write,
             "paged": self.paged.stats() if self.paged is not None else None,
             "compiles": tracing.recorder().compiles_in(self.replica_id),
         }

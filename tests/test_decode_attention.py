@@ -109,6 +109,58 @@ class TestDecodeAttentionKernel:
         b_ = A.decode_attention(q, k, v, pos, impl="kernel")
         np.testing.assert_allclose(np.array(a), np.array(b_), rtol=2e-3, atol=2e-3)
 
+    @pytest.mark.parametrize("kv", ["bf16", "int8"])
+    @pytest.mark.parametrize("at", ["first", "last"])
+    def test_stacked_cache_layer_index(self, kv, at):
+        """The in-place decode step on a stacked [L, B, HK, M, D] cache:
+        the kernel's row write lands at ``layer`` only (every other layer's
+        rows are untouched, and the layer equals the rewrite form's write)
+        and its attention at ``layer`` matches the oracle on that layer, the
+        new row included."""
+        n_layers, b, h, hk, m, d = 3, 3, 4, 2, 256, 32
+        layer = 0 if at == "first" else n_layers - 1
+        ks = jax.random.split(jax.random.PRNGKey(21), 5)
+        q = jax.random.normal(ks[0], (b, h, d), jnp.bfloat16)
+        k = jax.random.normal(ks[1], (n_layers, b, hk, m, d), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (n_layers, b, hk, m, d), jnp.bfloat16)
+        k_row = jax.random.normal(ks[3], (b, hk, d), jnp.bfloat16)
+        v_row = jax.random.normal(ks[4], (b, hk, d), jnp.bfloat16)
+        pos = jnp.array([200, 3, 255], jnp.int32)
+        cache = {"k": k, "v": v}
+        if kv == "int8":
+            (k, k_s), (v, v_s) = T.quantize_kv(k), T.quantize_kv(v)
+            cache = {"k": k, "v": v, "k_scale": k_s[..., None, :],
+                     "v_scale": v_s[..., None, :]}
+        got, *written = da_ops.decode_attention(
+            q, cache["k"], cache["v"], pos, k_scale=cache.get("k_scale"),
+            v_scale=cache.get("v_scale"), layer=jnp.int32(layer),
+            k_new=k_row, v_new=v_row, interpret=True)
+        new = dict(zip(("k", "v", "k_scale", "v_scale"), written))
+        others = np.array([i for i in range(n_layers) if i != layer])
+        for name in cache:
+            np.testing.assert_array_equal(np.array(new[name][others]),
+                                          np.array(cache[name][others]))
+        if kv == "int8":
+            k1, v1, ks1, vs1 = A.update_kv_cache_quant(
+                k[layer], v[layer], k_s[layer], v_s[layer], k_row, v_row, pos)
+            want = da_ref.decode_attention_quant_reference(
+                q, k1, v1, ks1, vs1, pos)
+            for name, w in (("k", k1), ("v", v1)):
+                np.testing.assert_array_equal(np.array(new[name][layer]),
+                                              np.array(w))
+            for name, w in (("k_scale", ks1), ("v_scale", vs1)):
+                np.testing.assert_array_equal(
+                    np.array(new[name][layer, :, :, 0]), np.array(w))
+        else:
+            k1, v1 = A.update_kv_cache(k[layer], v[layer], k_row, v_row, pos)
+            want = da_ref.decode_attention_reference(q, k1, v1, pos)
+            for name, w in (("k", k1), ("v", v1)):
+                np.testing.assert_array_equal(np.array(new[name][layer]),
+                                              np.array(w))
+        np.testing.assert_allclose(np.array(got, np.float32),
+                                   np.array(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+
     def test_schedule_blocks_tracks_frontier(self):
         live, dense = da_ops.schedule_blocks([64, 900], 1024, bkv=128)
         assert dense == 16
@@ -249,3 +301,87 @@ class TestGrowCaches:
             assert c.shape == s.shape
 
         rec(grown, shapes)
+
+
+# ---------------------------------------------------------------------------
+# The in-place KV path (DESIGN.md §decode) against the rewrite form
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("arch", ["tellme-0.7b", "gemma2-27b"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_in_place_ticks_bit_identical_to_rewrite(arch, kv, monkeypatch):
+    """Several engine-shaped ticks through the Pallas kernels (interpreted):
+    decode-only and fused decode + chunk ticks, slots at heterogeneous
+    positions, slots diverted into the trash tail. The in-place path — the
+    stacked cache carried through the layer loop, rows written in place —
+    gives logits and caches bit-identical to the rewrite form (scanned
+    per-layer caches, one-hot write) running the same kernels."""
+    cfg = dataclasses.replace(get_config(arch, smoke=True),
+                              kv_cache_dtype=kv)
+    params = P.init_params(Tr.param_specs(cfg), jax.random.PRNGKey(0))
+    b, c, trash = 4, 64, 192
+    cache_len = trash + c  # the engine's geometry: max_len 192, chunk 64
+    dead = cache_len - 1
+    # (decode positions, chunk offsets or None for a decode-only tick)
+    ticks = [([dead] * 4, [0, 0, trash, trash]),
+             ([64, dead, dead, dead], [trash, 64, 0, trash]),
+             ([65, 128, 64, dead], None),
+             ([66, 129, 65, dead], [trash, trash, 64, 0]),
+             ([67, 130, 128, 64], None)]
+
+    def run():
+        # traced afresh on each call, so each run sees the path in force
+        decode = jax.jit(lambda caches, tok, pos: Tr.decode_step(
+            params, {"tokens": tok}, caches, pos, cfg, mode="eval",
+            attn_impl="kernel"))
+        chunk_step = jax.jit(lambda caches, tok, off: Tr.prefill_chunk_step(
+            params, {"tokens": tok}, caches, off, cfg, mode="eval",
+            attn_impl="kernel", last_row=jnp.full((b,), c - 1),
+            prefix_limit=trash))
+        caches = E.init_caches(cfg, b, cache_len, dtype=jnp.bfloat16)
+        key = jax.random.PRNGKey(3)
+        outs = []
+        for dpos, off in ticks:
+            key, k1, k2 = jax.random.split(key, 3)
+            tok = jax.random.randint(k1, (b, 1), 0, cfg.vocab_size)
+            lg, caches = decode(caches, tok, jnp.array(dpos, jnp.int32))
+            outs.append(lg)
+            if off is not None:
+                chunk = jax.random.randint(k2, (b, c), 0, cfg.vocab_size)
+                lg, caches = chunk_step(caches, chunk,
+                                        jnp.array(off, jnp.int32))
+                outs.append(lg)
+        return outs, caches
+
+    assert Tr.kv_in_place("kernel")
+    got, got_caches = run()
+    monkeypatch.setattr(Tr, "kv_in_place", lambda *a, **k: False)
+    want, want_caches = run()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.array(g), np.array(w))
+    assert (jax.tree.structure(got_caches)
+            == jax.tree.structure(want_caches))
+    for g, w in zip(jax.tree.leaves(got_caches),
+                    jax.tree.leaves(want_caches)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.array(g), np.array(w))
+
+
+def test_stats_report_kv_write_path():
+    """``stats()["kv_write"]`` (``/v1/stats``) names the cache write the
+    ticks compiled: in place on the kernel path, the rewrite on the XLA form
+    (the CPU default) and on the paged layout."""
+    cfg = _cfg("tellme-0.7b")
+    params = P.init_params(Tr.param_specs(cfg), jax.random.PRNGKey(0))
+
+    def path(cfg, **kw):
+        eng = E.ServingEngine(params, cfg, slots=2, max_len=64, mode="eval",
+                              **kw)
+        return eng.stats()["kv_write"]
+
+    assert path(cfg) == "rewrite"
+    assert path(cfg, attn_impl="xla") == "rewrite"
+    assert path(cfg, attn_impl="kernel") == "in_place"
+    paged = dataclasses.replace(cfg, kv_layout="paged")
+    assert path(paged, attn_impl="kernel") == "rewrite"

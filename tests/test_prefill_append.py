@@ -18,6 +18,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core import params as P
+from repro.core import ternary as T
 from repro.kernels.prefill_append import ops as pa_ops
 from repro.kernels.prefill_append import ref as pa_ref
 from repro.models import attention as A
@@ -87,8 +88,8 @@ class TestPrefillAppendKernel:
         _assert_triple_close(got, want)
 
     def test_untouched_cache_rows_stay_resident(self):
-        # Only the chunk window [off, off+C) may change: the aliased output
-        # blocks never rewrite the rest of the cache.
+        # Only the chunk window [off, off+C) may change: the in-place append
+        # never rewrites the rest of the cache.
         q, kn, vn, kc, vc = _chunk_inputs(1, 2, 2, 64, 256, 16, key=11)
         off = jnp.array([64], jnp.int32)
         _, k2, v2 = pa_ops.prefill_append(q, kn, vn, kc, vc, off, interpret=True)
@@ -96,6 +97,49 @@ class TestPrefillAppendKernel:
         np.testing.assert_array_equal(np.array(k2[:, :, 128:]), np.array(kc[:, :, 128:]))
         np.testing.assert_allclose(np.array(k2[:, :, 64:128]),
                                    np.array(kn.astype(k2.dtype)), rtol=1e-6)
+
+    @pytest.mark.parametrize("kv", ["bf16", "int8"])
+    @pytest.mark.parametrize("at", ["first", "last"])
+    def test_stacked_cache_layer_index(self, kv, at):
+        """A stacked [L, B, HK, M, D] cache appended to at ``layer``: output
+        and that layer's cache match the oracle on the layer alone, and every
+        other layer comes back untouched."""
+        n_layers, b, h, hk, c, m, d = 3, 2, 4, 2, 64, 256, 32
+        layer = 0 if at == "first" else n_layers - 1
+        q, kn, vn, _, _ = _chunk_inputs(b, h, hk, c, m, d, key=31)
+        ks = jax.random.split(jax.random.PRNGKey(32), 2)
+        kc = jax.random.normal(ks[0], (n_layers, b, hk, m, d))
+        vc = jax.random.normal(ks[1], (n_layers, b, hk, m, d))
+        off = jnp.array([128, 0], jnp.int32)
+        if kv == "int8":
+            (kc, k_s), (vc, v_s) = T.quantize_kv(kc), T.quantize_kv(vc)
+            got = pa_ops.prefill_append(
+                q, kn, vn, kc, vc, off, k_scale=k_s[..., None, :],
+                v_scale=v_s[..., None, :], layer=jnp.int32(layer),
+                interpret=True)
+            before = (kc, vc, k_s[..., None, :], v_s[..., None, :])
+            want = pa_ref.prefill_append_quant_reference(
+                q, kn, vn, kc[layer], vc[layer], k_s[layer], v_s[layer], off)
+            # the oracle's scales are [B, HK, M]; the stack's [.., 1, M]
+            got_layer = [got[0]] + [g[layer] for g in got[1:3]] + [
+                g[layer, :, :, 0] for g in got[3:]]
+        else:
+            got = pa_ops.prefill_append(q, kn, vn, kc, vc, off,
+                                        layer=jnp.int32(layer),
+                                        interpret=True)
+            before = (kc, vc)
+            want = pa_ref.prefill_append_reference(q, kn, vn, kc[layer],
+                                                   vc[layer], off)
+            got_layer = [got[0]] + [g[layer] for g in got[1:]]
+        for g, w in zip(got_layer, want):
+            np.testing.assert_allclose(np.array(g, np.float32),
+                                       np.array(w, np.float32),
+                                       rtol=2e-3, atol=2e-3)
+        others = np.array([i for i in range(n_layers) if i != layer])
+        for g, w in zip(got[1:], before):
+            assert g.shape == w.shape
+            np.testing.assert_array_equal(np.array(g[others]),
+                                          np.array(w[others]))
 
     def test_models_impl_switch(self):
         """models.prefill_append_attention impl="kernel" ≡ impl="xla"."""

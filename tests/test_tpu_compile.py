@@ -17,10 +17,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core import params as Pr
 from repro.kernels.decode_attention import ops as da
 from repro.kernels.fused_norm_quant import ops as nq
 from repro.kernels.prefill_append import ops as pa
 from repro.kernels.ternary_matmul import ops as tm
+from repro.models import transformer as Tr
+from repro.serving import engine as E
 
 CFG = get_config("tellme-0.7b")
 D, FF, H, HK, HD = CFG.d_model, CFG.d_ff, CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
@@ -122,8 +125,8 @@ def test_decode_attention(one_chip, kv, layout):
 @pytest.mark.parametrize("layout", ["contiguous", "paged"])
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_prefill_append(one_chip, kv, layout):
-    """64-row chunks: the engine's narrowest, whose int8 scales are spliced
-    into a 128-lane window on the contiguous layout."""
+    """64-row chunks: the engine's narrowest, one page on the paged
+    layout."""
     paged = layout == "paged"
     c = 64
     cache, scales = _kv(kv, paged)
@@ -141,4 +144,76 @@ def test_prefill_append(one_chip, kv, layout):
 
     txt = _compile(fn, one_chip, chunk, chunk, chunk, cache, cache,
                    ((B,), I32), *table, *scales)
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("tick", ["decode", "fused64"])
+def test_tick_writes_cache_in_place(one_chip, monkeypatch, tick):
+    """A whole engine tick at ``tellme-0.7b`` widths, as the engine builds
+    it on a TPU (DESIGN.md §decode): the caches come back aliased to the
+    donated input, and no temporary comes near the cache's size — nothing
+    slices, rewrites, restacks or relayouts it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    max_len, c = 1024, 64
+    specs = Tr.param_specs(CFG)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: Tr.pack_tree(Pr.init_params(specs, k), specs),
+                       jax.random.PRNGKey(0)))
+    cache_specs = Tr.cache_specs(CFG, B, M, CFG.dtype)[0]
+    caches = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        cache_specs)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct((B,) + shape, dtype, sharding=one_chip)
+
+    if tick == "decode":
+        fn = E._serve_step_cached(CFG, "packed", "kernel", None)
+        low = fn.lower(params, {"tokens": vec(I32, 1)}, caches, vec(I32),
+                       None)
+    else:
+        fn = E._fused_tick_step(CFG, c, mode="packed", attn_impl="kernel",
+                                eos_id=-1, max_len=max_len, cache_len=M,
+                                trash_base=max_len)
+        b = jnp.bool_
+        low = fn.lower(params, caches, vec(I32), vec(I32), vec(b), vec(I32),
+                       vec(I32), vec(b), vec(I32, c), vec(I32), vec(b),
+                       vec(I32), vec(I32), None)
+    comp = low.compile()
+    ma = comp.memory_analysis()
+    cache_bytes = E.cache_nbytes(cache_specs)
+    assert "tpu_custom_call" in comp.as_text()
+    assert ma.alias_size_in_bytes >= cache_bytes
+    assert ma.temp_size_in_bytes < cache_bytes / 8, (
+        ma.temp_size_in_bytes, cache_bytes)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_stacked_kernels_unaligned_cache(one_chip, kv):
+    """The in-place path's kernel calls on a stacked cache whose length is
+    no multiple of 128 (576 rows, as ``chip_smoke.py``'s reference check
+    builds): the transposed kv blocks then span the whole cache row."""
+    n_layers, m, c = 2, 576, 64
+    cache, scales = ((n_layers, B, HK, m, HD), I8 if kv == "int8" else BF16), ()
+    if kv == "int8":
+        scales = (((n_layers, B, HK, 1, m), F32),) * 2
+
+    def decode(q, k, v, pos, kn, vn, *sc):
+        kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
+        return da.decode_attention(q, k, v, pos, layer=jnp.int32(1),
+                                   k_new=kn, v_new=vn, interpret=False, **kw)
+
+    def prefill(q, kn, vn, k, v, off, *sc):
+        kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
+        return pa.prefill_append(q, kn, vn, k, v, off, layer=jnp.int32(1),
+                                 interpret=False, **kw)
+
+    row = ((B, HK, HD), BF16)
+    txt = _compile(decode, one_chip, ((B, H, HD), BF16), cache, cache,
+                   ((B,), I32), row, row, *scales)
+    assert "tpu_custom_call" in txt
+    chunk = ((B, H, c, HD), BF16)
+    txt = _compile(prefill, one_chip, chunk, chunk, chunk, cache, cache,
+                   ((B,), I32), *scales)
     assert "tpu_custom_call" in txt
